@@ -885,7 +885,15 @@ def bench_sharded(args):
     ~N× the logical pages; under the same oversubscribed traffic the gated
     metric is peak resident tokens at mesh 2 vs mesh 1. tok/s is reported
     per mesh for context, not gated — fake CPU devices time-slice one
-    host, so sharded wall-clock says nothing about real multi-chip."""
+    host, so sharded wall-clock says nothing about real multi-chip.
+
+    Refused on a TPU host: the children would run on fake CPU devices
+    while this process holds the chip, and report CPU numbers from the
+    chip machine. The chip's mesh check is ``chip_smoke.py --chips 4``."""
+    if jax.default_backend() == "tpu":
+        raise SystemExit(
+            "the sharded section runs mesh engines on fake CPU devices; on "
+            "a TPU host run `python chip_smoke.py --chips 4` instead")
     per = {}
     for tp in args.sharded_meshes:
         spec = json.dumps({

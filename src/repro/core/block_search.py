@@ -26,10 +26,12 @@ import numpy as np
 
 from repro.core.bcr import BCRSpec, choose_block_shape
 
-# TPU v5e single-chip constants (see EXPERIMENTS.md §Roofline).
+# TPU v5e single-chip model constants for the analytic fitness: published
+# peaks (Google Cloud, "TPU v5e") and an assumed grid-step cost. They steer
+# block/plan choice only; none of them is a measured rate.
 PEAK_FLOPS = 197e12          # bf16
 HBM_BW = 819e9               # bytes/s
-GRID_STEP_OVERHEAD = 2e-7    # per-grid-step issue cost (overlapped w/ DMA)
+GRID_STEP_OVERHEAD = 2e-7    # assumed per-grid-step issue cost
 VMEM_BYTES = 128 * 1024 * 1024
 
 

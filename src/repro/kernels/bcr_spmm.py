@@ -58,9 +58,13 @@ from repro.core.bcrc import TBCRC
 _ORDERS = ("mij", "imj")
 
 
-def _block_update(x, vals, gather, scatter, scale=None):
+def _block_update(x, vals, gather, scatter, scale=None, *,
+                  scatter_t: bool = False):
     """gather → core tile matmul → scatter; returns the fp32 (M_t, br)
     contribution of one (i, j) block.
+
+    ``scatter`` is the ``(R_keep, br)`` one-hot, or with ``scatter_t`` its
+    transpose ``(br, R_keep)`` (contracted on its minor dim).
 
     ``scale``: per-block dequant scalar for int8 ``vals`` — folded into
     the fp32 partial BEFORE the scatter (exact, the scatter one-hot is
@@ -75,23 +79,39 @@ def _block_update(x, vals, gather, scatter, scale=None):
     )
     if scale is not None:
         part = part * scale
-    return jnp.dot(part, scatter, preferred_element_type=jnp.float32)
+    return jax.lax.dot_general(
+        part, scatter, dimension_numbers=(((1,), (int(scatter_t),)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
 def _onehots(cols, rows, block_rows, block_cols, dtype):
-    """Index planes → one-hot gather/scatter (VPU iota + compare)."""
+    """Index rows ``(1, C_keep)`` / ``(1, R_keep)`` → one-hot gather
+    ``(bc, C_keep)`` and TRANSPOSED scatter ``(br, R_keep)`` (VPU iota +
+    compare; both broadcast the index row down the sublanes, so no
+    lane→sublane transpose is needed)."""
     iota_c = jax.lax.broadcasted_iota(jnp.int32,
-                                      (block_cols, cols.shape[0]), 0)
-    gather = (iota_c == cols[None, :]).astype(dtype)
+                                      (block_cols, cols.shape[-1]), 0)
+    gather = (iota_c == cols).astype(dtype)
     iota_r = jax.lax.broadcasted_iota(jnp.int32,
-                                      (rows.shape[0], block_rows), 1)
-    scatter = (iota_r == rows[:, None]).astype(jnp.float32)
-    return gather, scatter
+                                      (block_rows, rows.shape[-1]), 0)
+    scatter_t = (iota_r == rows).astype(jnp.float32)
+    return gather, scatter_t
+
+
+def _block_scale(row, j):
+    """Per-block dequant scale ``scales[i, j]`` as a ``(1, 1)`` value.
+
+    The whole ``(nb_r, nb_c)`` scale matrix is one VMEM block (its minor
+    dims are then full, as the TPU lowering requires of a block); the
+    caller loads row ``i`` as a dynamic sublane slice ``(1, nb_c)`` and
+    lane ``j`` is picked here by a masked lane reduction."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    return jnp.sum(jnp.where(lane == j, row, 0.0), axis=1, keepdims=True)
 
 
 def _kernel_idx(x_ref, vals_ref, row_ref, col_ref, *rest,
                 nb_c: int, block_rows: int, block_cols: int,
-                has_scale: bool):
+                has_scale: bool, row_axis: int):
     scale_ref = rest[0] if has_scale else None
     o_ref, acc_ref = rest[-2], rest[-1]
     j = pl.program_id(2)  # contraction dim is innermost in both orders
@@ -101,11 +121,14 @@ def _kernel_idx(x_ref, vals_ref, row_ref, col_ref, *rest,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...]                      # (M_t, bc)
-    gather, scatter = _onehots(col_ref[0, 0, :], row_ref[0, 0, :],
-                               block_rows, block_cols, x.dtype)
-    acc_ref[...] += _block_update(
-        x, vals_ref[0, 0], gather, scatter,
-        scale_ref[0, 0] if has_scale else None)
+    # index planes arrive as the block row's whole (nb_c, keep) slab
+    gather, scatter_t = _onehots(col_ref[0, pl.ds(j, 1), :],
+                                 row_ref[0, pl.ds(j, 1), :],
+                                 block_rows, block_cols, x.dtype)
+    scale = (_block_scale(scale_ref[pl.ds(pl.program_id(row_axis), 1), :], j)
+             if has_scale else None)
+    acc_ref[...] += _block_update(x, vals_ref[0, 0], gather, scatter_t,
+                                  scale, scatter_t=True)
 
     @pl.when(j == nb_c - 1)
     def _emit():
@@ -113,7 +136,7 @@ def _kernel_idx(x_ref, vals_ref, row_ref, col_ref, *rest,
 
 
 def _kernel_planes(x_ref, vals_ref, gpl_ref, spl_ref, *rest,
-                   nb_c: int, has_scale: bool):
+                   nb_c: int, has_scale: bool, row_axis: int):
     scale_ref = rest[0] if has_scale else None
     o_ref, acc_ref = rest[-2], rest[-1]
     j = pl.program_id(2)
@@ -125,9 +148,9 @@ def _kernel_planes(x_ref, vals_ref, gpl_ref, spl_ref, *rest,
     x = x_ref[...]
     gather = gpl_ref[0, 0].astype(x.dtype)          # (bc, C_keep) int8 DMA
     scatter = spl_ref[0, 0].astype(jnp.float32)     # (R_keep, br)
-    acc_ref[...] += _block_update(
-        x, vals_ref[0, 0], gather, scatter,
-        scale_ref[0, 0] if has_scale else None)
+    scale = (_block_scale(scale_ref[pl.ds(pl.program_id(row_axis), 1), :], j)
+             if has_scale else None)
+    acc_ref[...] += _block_update(x, vals_ref[0, 0], gather, scatter, scale)
 
     @pl.when(j == nb_c - 1)
     def _emit():
@@ -152,6 +175,11 @@ def _grid_and_maps(order: str, m_steps: int, nb_r: int, nb_c: int):
     x_map = lambda *g: (norm(*g)[0], norm(*g)[2])
     out_map = lambda *g: (norm(*g)[0], norm(*g)[1])
     return grid, norm, x_map, out_map
+
+
+def _row_axis(order: str) -> int:
+    """Grid axis that walks the output block rows ``i``."""
+    return 1 if order == "mij" else 0
 
 
 @functools.partial(jax.jit, static_argnames=("m_tile", "interpret"))
@@ -189,32 +217,36 @@ def bcr_spmm(
 
     grid, norm, x_map, out_map = _grid_and_maps(order, m_steps, nb_r, nb_c)
     tile_i = lambda *g: (norm(*g)[1], norm(*g)[2], 0, 0)
-    plane_i = lambda *g: (norm(*g)[1], norm(*g)[2], 0, 0)
-    scale_i = lambda *g: (norm(*g)[1], norm(*g)[2])
+    # Every block below keeps its two minor dims full or (8, 128)-aligned,
+    # as the TPU lowering requires: index planes come in as the block
+    # row's whole (nb_c, keep) slab and scales as the whole matrix; the
+    # kernel picks column j (and row i) itself.
+    idx_i = lambda *g: (norm(*g)[1], 0, 0)
 
     if use_planes:
         kernel = functools.partial(_kernel_planes, nb_c=nb_c,
-                                   has_scale=has_scale)
+                                   has_scale=has_scale,
+                                   row_axis=_row_axis(order))
         in_specs = [
             pl.BlockSpec((m_tile, bc), x_map),
             pl.BlockSpec((1, 1, r_keep, c_keep), tile_i),
-            pl.BlockSpec((1, 1, bc, c_keep), plane_i),
-            pl.BlockSpec((1, 1, r_keep, br), plane_i),
+            pl.BlockSpec((1, 1, bc, c_keep), tile_i),
+            pl.BlockSpec((1, 1, r_keep, br), tile_i),
         ]
         operands = [x, packed.vals, plan.gather_planes, plan.scatter_planes]
     else:
         kernel = functools.partial(
             _kernel_idx, nb_c=nb_c, block_rows=br, block_cols=bc,
-            has_scale=has_scale)
+            has_scale=has_scale, row_axis=_row_axis(order))
         in_specs = [
             pl.BlockSpec((m_tile, bc), x_map),
             pl.BlockSpec((1, 1, r_keep, c_keep), tile_i),
-            pl.BlockSpec((1, 1, r_keep), lambda *g: (norm(*g)[1], norm(*g)[2], 0)),
-            pl.BlockSpec((1, 1, c_keep), lambda *g: (norm(*g)[1], norm(*g)[2], 0)),
+            pl.BlockSpec((1, nb_c, r_keep), idx_i),
+            pl.BlockSpec((1, nb_c, c_keep), idx_i),
         ]
         operands = [x, packed.vals, packed.row_idx, packed.col_idx]
     if has_scale:
-        in_specs.append(pl.BlockSpec((1, 1), scale_i))
+        in_specs.append(pl.BlockSpec((nb_r, nb_c), lambda *g: (0, 0)))
         operands.append(plan.block_scales)
 
     out = pl.pallas_call(
@@ -258,7 +290,7 @@ def _grouped_emit(o_ref, acc_ref, bias_ref, epilogue):
 def _grouped_kernel_idx(x_ref, vals_ref, row_ref, col_ref, *rest,
                         nb_c: int, block_rows: int, block_cols: int,
                         group: int, has_scale: bool, has_bias: bool,
-                        epilogue):
+                        epilogue, row_axis: int):
     scale_ref = rest[0] if has_scale else None
     bias_ref = rest[int(has_scale)] if has_bias else None
     o_ref, acc_ref = rest[-2], rest[-1]
@@ -269,12 +301,15 @@ def _grouped_kernel_idx(x_ref, vals_ref, row_ref, col_ref, *rest,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...]                      # DMA'd ONCE for the whole group
+    i = pl.program_id(row_axis)
     for g in range(group):              # static unroll
-        gather, scatter = _onehots(col_ref[g, 0, 0, :], row_ref[g, 0, 0, :],
-                                   block_rows, block_cols, x.dtype)
-        acc_ref[g] += _block_update(
-            x, vals_ref[g, 0, 0], gather, scatter,
-            scale_ref[g, 0, 0] if has_scale else None)
+        gather, scatter_t = _onehots(col_ref[g, 0, pl.ds(j, 1), :],
+                                     row_ref[g, 0, pl.ds(j, 1), :],
+                                     block_rows, block_cols, x.dtype)
+        scale = (_block_scale(scale_ref[g, pl.ds(i, 1), :], j)
+                 if has_scale else None)
+        acc_ref[g] += _block_update(x, vals_ref[g, 0, 0], gather, scatter_t,
+                                    scale, scatter_t=True)
 
     @pl.when(j == nb_c - 1)
     def _emit():
@@ -283,7 +318,7 @@ def _grouped_kernel_idx(x_ref, vals_ref, row_ref, col_ref, *rest,
 
 def _grouped_kernel_planes(x_ref, vals_ref, gpl_ref, spl_ref, *rest,
                            nb_c: int, group: int, has_scale: bool,
-                           has_bias: bool, epilogue):
+                           has_bias: bool, epilogue, row_axis: int):
     scale_ref = rest[0] if has_scale else None
     bias_ref = rest[int(has_scale)] if has_bias else None
     o_ref, acc_ref = rest[-2], rest[-1]
@@ -294,12 +329,14 @@ def _grouped_kernel_planes(x_ref, vals_ref, gpl_ref, spl_ref, *rest,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...]
+    i = pl.program_id(row_axis)
     for g in range(group):
         gather = gpl_ref[g, 0, 0].astype(x.dtype)
         scatter = spl_ref[g, 0, 0].astype(jnp.float32)
-        acc_ref[g] += _block_update(
-            x, vals_ref[g, 0, 0], gather, scatter,
-            scale_ref[g, 0, 0] if has_scale else None)
+        scale = (_block_scale(scale_ref[g, pl.ds(i, 1), :], j)
+                 if has_scale else None)
+        acc_ref[g] += _block_update(x, vals_ref[g, 0, 0], gather, scatter,
+                                    scale)
 
     @pl.when(j == nb_c - 1)
     def _emit():
@@ -357,7 +394,8 @@ def bcr_spmm_grouped(
         kernel = functools.partial(_grouped_kernel_planes, nb_c=nb_c,
                                    group=g_size, has_scale=has_scale,
                                    has_bias=bias is not None,
-                                   epilogue=epilogue)
+                                   epilogue=epilogue,
+                                   row_axis=_row_axis(order))
         in_specs = [
             pl.BlockSpec((m_tile, bc), x_map),
             pl.BlockSpec((g_size, 1, 1, r_keep, c_keep), tile_i),
@@ -369,19 +407,18 @@ def bcr_spmm_grouped(
         kernel = functools.partial(
             _grouped_kernel_idx, nb_c=nb_c, block_rows=br, block_cols=bc,
             group=g_size, has_scale=has_scale, has_bias=bias is not None,
-            epilogue=epilogue)
+            epilogue=epilogue, row_axis=_row_axis(order))
+        idx_i = lambda *g: (0, norm(*g)[1], 0, 0)
         in_specs = [
             pl.BlockSpec((m_tile, bc), x_map),
             pl.BlockSpec((g_size, 1, 1, r_keep, c_keep), tile_i),
-            pl.BlockSpec((g_size, 1, 1, r_keep),
-                         lambda *g: (0, norm(*g)[1], norm(*g)[2], 0)),
-            pl.BlockSpec((g_size, 1, 1, c_keep),
-                         lambda *g: (0, norm(*g)[1], norm(*g)[2], 0)),
+            pl.BlockSpec((g_size, 1, nb_c, r_keep), idx_i),
+            pl.BlockSpec((g_size, 1, nb_c, c_keep), idx_i),
         ]
         operands = [x, grouped.vals, grouped.row_idx, grouped.col_idx]
     if has_scale:
-        in_specs.append(pl.BlockSpec(
-            (g_size, 1, 1), lambda *g: (0, norm(*g)[1], norm(*g)[2])))
+        in_specs.append(pl.BlockSpec((g_size, nb_r, nb_c),
+                                     lambda *g: (0, 0, 0)))
         operands.append(plan.block_scales)
     if bias is not None:
         in_specs.append(pl.BlockSpec(

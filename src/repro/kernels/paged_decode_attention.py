@@ -5,7 +5,7 @@ D)`` cache every step and relies on a ``-1e30`` mask to discard dead
 positions — bytes per step scale with provisioned capacity, not with what
 any request has actually generated. This kernel applies GRIM's core move
 (skip pruned blocks at block granularity instead of masking them) to the
-KV cache: K/V live in a shared page pool ``(n_pages, page_size, Hkv, D)``
+KV cache: K/V live in a shared page pool ``(n_pages, Hkv, page_size, D)``
 and each slot owns a block table of physical page ids, so the grid only
 *reads* each slot's live pages.
 
@@ -42,6 +42,12 @@ grid = (B, Hkv, n_table_cols), pages innermost. Per (slot b, kv-head h):
 VMEM residency per (b, h): q (S*G, D), one K page + one V page, and the
 (S*G, 1)/(S*G, D) online-softmax state — independent of context length
 (but linear in the suffix chunk S, which the engine buckets).
+
+The pool is head-major so that one (page, kv-head) block has the full
+``(page_size, D)`` as its two minor dims — the TPU lowering requires a
+block's minor dims to be (8, 128)-aligned or full. int8 scale pools are
+``(n_pages, Hkv, page_size)``; their block is the whole ``(Hkv,
+page_size)`` page slab (again: full minor dims) and the body picks row h.
 """
 
 from __future__ import annotations
@@ -67,6 +73,7 @@ def _kernel(bt_ref, live_ref, plen_ref, q_ref, k_ref, v_ref, *rest,
         o_ref, m_ref, l_ref, acc_ref = rest
     p = pl.program_id(2)                  # logical page of this slot
     b = pl.program_id(0)
+    h = pl.program_id(1)
 
     @pl.when(p == 0)
     def _init():
@@ -79,8 +86,8 @@ def _kernel(bt_ref, live_ref, plen_ref, q_ref, k_ref, v_ref, *rest,
     @pl.when(p < live_ref[b])
     def _page():
         q = q_ref[0, 0]                   # (S*G padded, D)
-        k = k_ref[0, :, 0, :]             # (page_size, D)
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0, 0]                   # (page_size, D)
+        v = v_ref[0, 0]
         if quantized:
             # int8 pages: the matmul runs on the raw codes and the
             # per-row-per-head scale is folded into the logits columns
@@ -92,7 +99,7 @@ def _kernel(bt_ref, live_ref, plen_ref, q_ref, k_ref, v_ref, *rest,
             q, k, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # (rows, page_size)
         if quantized:
-            s = s * ks_ref[0].reshape(1, page_size)
+            s = s * ks_ref[0, pl.ds(h, 1), :]      # (1, page_size)
         # per-row causal mask: row r is q-head r % G of suffix position
         # r // G, at absolute position plen + r // G. For decode (S=1)
         # this degenerates to the uniform ``pos < cache_len`` mask; rows
@@ -109,7 +116,7 @@ def _kernel(bt_ref, live_ref, plen_ref, q_ref, k_ref, v_ref, *rest,
         if quantized:
             # fold the V scale into the probability columns, then run the
             # weighted sum on the raw int8 codes in fp32
-            pv = prob * vs_ref[0].reshape(1, page_size)
+            pv = prob * vs_ref[0, pl.ds(h, 1), :]
             acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
                 pv, v.astype(jnp.float32),
                 preferred_element_type=jnp.float32)
@@ -132,12 +139,12 @@ def _paged_attention(q, k_pages, v_pages, block_tables, prefix_len,
     mask. Returns (B, S, H, D).
 
     When ``k_scale``/``v_scale`` are given the pools hold int8 codes and
-    the sibling ``(n_pages, page_size, Hkv)`` scale pools carry one fp32
+    the sibling ``(n_pages, Hkv, page_size)`` scale pools carry one fp32
     scale per page row per kv head; scale tiles ride the same clamped
     index map as their pages (so dead steps elide the scale DMA too) and
     dequantization happens inside the kernel body."""
     b, s, h, d = q.shape
-    n_pages, page_size, hkv, _ = k_pages.shape
+    n_pages, hkv, page_size, _ = k_pages.shape
     g = h // hkv
     n_cols = block_tables.shape[1]
     scale = d ** -0.5
@@ -162,23 +169,23 @@ def _paged_attention(q, k_pages, v_pages, block_tables, prefix_len,
         # unchanged from the previous step, so Pallas skips the DMA —
         # per-slot HBM traffic is live pages, not table width
         col = jnp.minimum(p_, jnp.maximum(live_ref[b_] - 1, 0))
-        return bt_ref[b_, col], 0, h_, 0
+        return bt_ref[b_, col], h_, 0, 0
 
     def s_map(b_, h_, p_, bt_ref, live_ref, plen_ref):
         col = jnp.minimum(p_, jnp.maximum(live_ref[b_] - 1, 0))
-        return bt_ref[b_, col], 0, h_
+        return bt_ref[b_, col], 0, 0
 
     quantized = k_scale is not None
     in_specs = [
         pl.BlockSpec((1, 1, rp, d),
                      lambda b_, h_, p_, *refs: (b_, h_, 0, 0)),
-        pl.BlockSpec((1, page_size, 1, d), k_map),
-        pl.BlockSpec((1, page_size, 1, d), k_map),
+        pl.BlockSpec((1, 1, page_size, d), k_map),
+        pl.BlockSpec((1, 1, page_size, d), k_map),
     ]
     operands = [qg, k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page_size, 1), s_map),
-                     pl.BlockSpec((1, page_size, 1), s_map)]
+        in_specs += [pl.BlockSpec((1, hkv, page_size), s_map),
+                     pl.BlockSpec((1, hkv, page_size), s_map)]
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -210,12 +217,12 @@ def _paged_attention(q, k_pages, v_pages, block_tables, prefix_len,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention(
     q: jax.Array,              # (B, 1, H, D)
-    k_pages: jax.Array,        # (n_pages, page_size, Hkv, D)
+    k_pages: jax.Array,        # (n_pages, Hkv, page_size, D)
     v_pages: jax.Array,
     block_tables: jax.Array,   # (B, n_cols) int32 physical page ids
     cache_len: jax.Array,      # (B,) valid positions incl. the new token
     *,
-    k_scale: jax.Array | None = None,  # (n_pages, page_size, Hkv) fp32
+    k_scale: jax.Array | None = None,  # (n_pages, Hkv, page_size) fp32
     v_scale: jax.Array | None = None,
     interpret: bool = False,
 ) -> jax.Array:
@@ -238,13 +245,13 @@ def paged_decode_attention(
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_prefill_append_attention(
     q: jax.Array,              # (B, S, H, D) — S suffix rows per slot
-    k_pages: jax.Array,        # (n_pages, page_size, Hkv, D)
+    k_pages: jax.Array,        # (n_pages, Hkv, page_size, D)
     v_pages: jax.Array,
     block_tables: jax.Array,   # (B, n_cols) int32 physical page ids
     prefix_len: jax.Array,     # (B,) cached positions BEFORE the suffix
     total_len: jax.Array,      # (B,) prefix_len + true suffix length
     *,
-    k_scale: jax.Array | None = None,  # (n_pages, page_size, Hkv) fp32
+    k_scale: jax.Array | None = None,  # (n_pages, Hkv, page_size) fp32
     v_scale: jax.Array | None = None,
     interpret: bool = False,
 ) -> jax.Array:

@@ -7,7 +7,7 @@ dequantize and no new gather is introduced:
 
 * **KV rows** — one fp32 scale per cache row per kv head (axis ``-1``
   absmax over ``head_dim``). The paged pools keep the scales in sibling
-  ``(n_pages, page_size, Hkv)`` pools that share the K/V page index map,
+  ``(n_pages, Hkv, page_size)`` pools that share the K/V page index map,
   so CoW page copies, truncation and DMA elision all apply to the scales
   for free.
 * **BCR block values** — one fp32 scale per kept ``(r_keep, c_keep)``

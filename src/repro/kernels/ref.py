@@ -129,12 +129,15 @@ def bcr_spmm_gather_ref(x: jax.Array, packed: TBCRC) -> jax.Array:
 
 
 def _gather_dequant(pages, scale, block_tables, b, l, hkv, d):
-    """Gather table pages into a contiguous (B, L, Hkv, D) history,
-    dequantizing off the sibling per-row-per-head scale pool when the
-    pages hold int8 codes."""
-    k = jnp.take(pages, block_tables, axis=0).reshape(b, l, hkv, d)
+    """Gather head-major table pages ``(n_pages, Hkv, page_size, D)`` into
+    a contiguous (B, L, Hkv, D) history, dequantizing off the sibling
+    ``(n_pages, Hkv, page_size)`` scale pool when the pages hold int8
+    codes."""
+    k = jnp.take(pages, block_tables, axis=0)       # (B, cols, Hkv, ps, D)
+    k = jnp.swapaxes(k, 2, 3).reshape(b, l, hkv, d)
     if scale is not None:
-        sc = jnp.take(scale, block_tables, axis=0).reshape(b, l, hkv)
+        sc = jnp.take(scale, block_tables, axis=0)
+        sc = jnp.swapaxes(sc, 2, 3).reshape(b, l, hkv)
         k = k.astype(jnp.float32) * sc.astype(jnp.float32)[..., None]
     return k
 
@@ -146,21 +149,21 @@ def paged_decode_attention_ref(q: jax.Array, k_pages: jax.Array,
     """Pure-JAX oracle for the paged flash-decode kernel: gather each
     slot's table pages, then masked single-step attention.
 
-    q ``(B, 1, H, D)``; pages ``(n_pages, page_size, Hkv, D)``; tables
+    q ``(B, 1, H, D)``; pages ``(n_pages, Hkv, page_size, D)``; tables
     ``(B, n_cols)``; cache_len ``(B,)`` counts valid positions including
     the step's new token. With ``k_scale``/``v_scale`` the pages hold
-    int8 codes dequantized off the ``(n_pages, page_size, Hkv)`` scale
+    int8 codes dequantized off the ``(n_pages, Hkv, page_size)`` scale
     pools after the gather. Bytes read scale with the table WIDTH handed
     in (the engine buckets it to the longest live slot) — the Pallas
     kernel further drops per-slot dead columns via its index-map clamp.
     """
     b, s, h, d = q.shape
     assert s == 1
-    n_pages, page_size, hkv, _ = k_pages.shape
+    n_pages, hkv, page_size, _ = k_pages.shape
     g = h // hkv
     n_cols = block_tables.shape[1]
     l = n_cols * page_size
-    # (B, n_cols, page_size, Hkv, D) -> (B, L, Hkv, D) contiguous history
+    # (B, n_cols, Hkv, page_size, D) -> (B, L, Hkv, D) contiguous history
     k = _gather_dequant(k_pages, k_scale, block_tables, b, l, hkv, d)
     v = _gather_dequant(v_pages, v_scale, block_tables, b, l, hkv, d)
     qg = q.reshape(b, hkv, g, d).astype(k.dtype)
@@ -182,7 +185,7 @@ def paged_prefill_append_ref(q: jax.Array, k_pages: jax.Array,
     slot's table pages, then causally masked attention for an S-row query
     block whose row ``i`` sits at absolute position ``prefix_len[b] + i``.
 
-    q ``(B, S, H, D)``; pages ``(n_pages, page_size, Hkv, D)``; tables
+    q ``(B, S, H, D)``; pages ``(n_pages, Hkv, page_size, D)``; tables
     ``(B, n_cols)``; ``prefix_len`` counts cached positions before the
     suffix, ``total_len = prefix_len + true suffix length`` bounds the
     live positions. The suffix K/V must already be scattered into the
@@ -191,7 +194,7 @@ def paged_prefill_append_ref(q: jax.Array, k_pages: jax.Array,
     suffix length produce garbage that the caller discards.
     """
     b, s, h, d = q.shape
-    n_pages, page_size, hkv, _ = k_pages.shape
+    n_pages, hkv, page_size, _ = k_pages.shape
     g = h // hkv
     l = block_tables.shape[1] * page_size
     k = _gather_dequant(k_pages, k_scale, block_tables, b, l, hkv, d)

@@ -20,11 +20,11 @@ from __future__ import annotations
 
 import argparse
 import asyncio
-import dataclasses
+import sys
+from typing import Optional, Sequence
 
-from repro.configs import get_config, get_smoke_config
-from repro.launch.serve import build_draft, build_params
-from repro.serving import EngineConfig, InferenceEngine, TenantQuota
+from repro.launch.serve import build_engine, enable_compile_cache
+from repro.serving import TenantQuota
 from repro.serving.server import InferenceServer, ServerConfig
 
 
@@ -52,7 +52,7 @@ def parse_tenant_quotas(specs) -> dict:
     return quotas
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True)
     p.add_argument("--smoke", action="store_true")
@@ -69,8 +69,11 @@ def main() -> None:
     p.add_argument("--draft-bcr-keep", type=float, default=0.0)
     p.add_argument("--bcr-keep", type=float, default=0.0)
     p.add_argument("--bcr-block", type=int, default=0)
-    p.add_argument("--impl", default="ref",
-                   choices=["ref", "interpret", "pallas"])
+    p.add_argument("--impl", default=None,
+                   choices=["ref", "interpret", "pallas"],
+                   help="kernel backend (default: pallas on a TPU, ref "
+                        "elsewhere; ref is the pure-JAX oracle, interpret "
+                        "is CPU-only)")
     p.add_argument("--kv-dtype", default="", choices=["", "int8"])
     p.add_argument("--weight-dtype", default="", choices=["", "int8"])
     p.add_argument("--mesh-model", type=int, default=1,
@@ -130,38 +133,29 @@ def main() -> None:
                         "immediately; first requests pay jit)")
     p.add_argument("--warmup-lens", type=int, nargs="*", default=[16, 32],
                    help="prompt lengths to compile ahead of readiness")
-    args = p.parse_args()
+    return p
 
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    cfg = dataclasses.replace(cfg, bcr_keep_frac=args.bcr_keep,
-                              kernel_impl=args.impl)
-    if args.bcr_block or args.smoke:
-        b = args.bcr_block or 16
-        cfg = dataclasses.replace(cfg, bcr_block=(b, b))
-    params = build_params(cfg, decode_m=args.slots,
-                          weight_dtype=args.weight_dtype)
-    if args.prefix_cache and not args.page_size:
-        p.error("--prefix-cache needs --page-size (paged KV pool)")
-    if args.spec_k and not args.page_size:
-        p.error("--spec-k needs --page-size")
-    draft_cfg, draft_params = None, None
-    if args.spec_k:
-        draft_cfg, draft_params = build_draft(cfg, args)
-    engine = InferenceEngine(cfg, params, EngineConfig(
-        n_slots=args.slots, capacity=args.capacity,
-        page_size=args.page_size, kv_pages=args.kv_pages or None,
-        prefix_cache=args.prefix_cache,
-        spec_k=args.spec_k, draft_cfg=draft_cfg,
-        kv_dtype=args.kv_dtype,
-        max_waiting=args.max_waiting or None,
-        preempt_after_stalls=args.preempt_after_stalls,
-        slo_admission=args.slo_admission, slo_slack=args.slo_slack,
-        mesh_model=args.mesh_model,
+
+def build_api_engine(args):
+    """The engine ``main`` serves, from parsed :func:`build_parser` args:
+    the shared ``serve.build_engine`` plus the per-tenant quotas only this
+    entry point has."""
+    return build_engine(
+        args,
         tenant_quotas=parse_tenant_quotas(args.tenant) or None,
         default_tenant_quota=(
             parse_tenant_quotas(["_," + args.default_tenant_quota])["_"]
-            if args.default_tenant_quota else None)),
-        draft_params=draft_params)
+            if args.default_tenant_quota else None))
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    p = build_parser()
+    args = p.parse_args(argv)
+    enable_compile_cache()
+    try:
+        engine = build_api_engine(args)
+    except ValueError as e:
+        p.error(str(e))
     server = InferenceServer(engine, ServerConfig(
         host=args.host, port=args.port,
         default_max_tokens=args.default_max_tokens,
@@ -174,7 +168,7 @@ def main() -> None:
         keepalive_idle_s=args.keepalive_idle_s,
         max_requests_per_conn=args.max_conn_requests))
     warmup = None if args.no_warmup else args.warmup_lens
-    print(f"serving {cfg.name} on http://{args.host}:{args.port} "
+    print(f"serving {engine.cfg.name} on http://{args.host}:{args.port} "
           f"(slots={args.slots}, page_size={args.page_size}, "
           f"warmup={'off' if warmup is None else warmup})")
     try:
@@ -183,6 +177,8 @@ def main() -> None:
         pass
     print("drained; conservation "
           + ("ok" if server.conservation_ok else "FAILED"))
+    if not server.conservation_ok:
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -161,18 +161,9 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
             val = getattr(mem, field, None)
             if val is not None:
                 mem_rec[field] = int(val)
-        if mem_rec and "peak_memory_in_bytes" not in mem_rec:
-            # newer jax drops the field on CPU; conservative upper bound
-            mem_rec["peak_memory_in_bytes"] = (
-                mem_rec.get("temp_size_in_bytes", 0)
-                + mem_rec.get("argument_size_in_bytes", 0)
-                + mem_rec.get("output_size_in_bytes", 0)
-                - mem_rec.get("alias_size_in_bytes", 0))
         record["memory_analysis"] = mem_rec or str(mem)
 
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # newer jax: one dict per program
-            ca = ca[0] if ca else {}
         record["xla_cost_analysis"] = {
             k: float(v) for k, v in ca.items()
             if isinstance(v, (int, float)) and k in

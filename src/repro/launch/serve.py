@@ -2,8 +2,11 @@
 
 The GRIM deployment path: take (ADMM-pruned) dense weights → pack every
 prunable projection into TBCRC (kernel format) → serve a continuous-batching
-decode loop whose weight traffic is keep_frac × dense. On this CPU box the
-kernel runs in Pallas interpret mode; impl="ref" is the fast-on-CPU fallback.
+decode loop whose weight traffic is keep_frac × dense. The kernel backend
+is derived from the platform (:func:`select_backend`): on a TPU the compiled
+Pallas kernels serve by default, elsewhere the pure-JAX references do;
+``--impl`` overrides it (``ref`` is the oracle on any platform, ``interpret``
+runs the kernel bodies in Pallas interpret mode and is CPU-only).
 
 Two modes:
 
@@ -28,8 +31,10 @@ import argparse
 import dataclasses
 import functools
 import json
+import os
 import time
-from typing import Any, Dict, List
+from pathlib import Path
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +44,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.configs.base import ModelConfig
 from repro.core.bcr import BCRSpec, kept_align
 from repro.core.bcrc import tbcrc_pack
+from repro.kernels.ops import default_impl
 from repro.launch.train import default_prune_filter
 from repro.models.api import model_fns
 from repro.serving import EngineConfig, InferenceEngine
@@ -479,6 +485,94 @@ def run_traffic(engine: InferenceEngine, tc: TrafficConfig, log=print
 # ---------------------------------------------------------------------------
 
 
+def enable_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at a fixed directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, which JAX reads itself, wins when set;
+    otherwise the cache lives in ``.jax_cache/`` at the root of the
+    checkout. The path must not move between runs (it is part of what a
+    cached entry is found by), so it is never a temporary, pid- or
+    time-derived directory. Call before the first compile; returns the
+    directory in use."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def select_backend(impl: Optional[str], page_size: int
+                   ) -> Tuple[str, Optional[str]]:
+    """``(kernel_impl, attn_impl)`` for this platform — the one place the
+    serving entry points choose kernels.
+
+    No ``impl`` → :func:`repro.kernels.ops.default_impl`: the compiled
+    Pallas kernels on a TPU, the pure-JAX references elsewhere. With a
+    paged pool (``page_size > 0``) the paged attention kernel follows the
+    BCR kernels ("paged" / "paged_interpret"); ``attn_impl`` None keeps the
+    config's own (the pure-JAX attention paths). ``interpret`` on a TPU and
+    ``pallas`` off one are errors, not silent fallbacks."""
+    platform = jax.default_backend()
+    impl = impl or default_impl()
+    if impl == "interpret" and platform == "tpu":
+        raise ValueError("--impl interpret runs the kernels in the Pallas "
+                         "interpreter; on a TPU use pallas (or ref as the "
+                         "oracle)")
+    if impl == "pallas" and platform != "tpu":
+        raise ValueError(f"--impl pallas needs a TPU backend, found "
+                         f"{platform!r} (use interpret or ref)")
+    attn = {"pallas": "paged", "interpret": "paged_interpret"}.get(impl)
+    return impl, (attn if page_size else None)
+
+
+def build_config(args) -> ModelConfig:
+    """Model config for the serving CLIs' shared flags (``arch``,
+    ``smoke``, ``bcr_keep``, ``bcr_block``, ``impl``, ``page_size``), with
+    the kernel backend from :func:`select_backend`."""
+    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    kernel_impl, attn_impl = select_backend(args.impl, args.page_size)
+    cfg = dataclasses.replace(cfg, bcr_keep_frac=args.bcr_keep,
+                              kernel_impl=kernel_impl)
+    if attn_impl:
+        cfg = dataclasses.replace(cfg, attn_impl=attn_impl)
+    if args.bcr_block or args.smoke:
+        b = args.bcr_block or 16
+        cfg = dataclasses.replace(cfg, bcr_block=(b, b))
+    return cfg
+
+
+def build_engine(args, log=print, **engine_kw) -> InferenceEngine:
+    """Config, random-init (BCR-packed) params, optional drafter and the
+    engine, from the flags ``launch/serve.py`` and ``launch/api.py`` share.
+    ``engine_kw`` adds EngineConfig fields only one entry point has.
+    Raises ValueError for flag combinations the engine cannot serve."""
+    if args.prefix_cache and not args.page_size:
+        raise ValueError("--prefix-cache needs --page-size (paged KV pool)")
+    if args.spec_k and not args.page_size:
+        raise ValueError("--spec-k needs --page-size (verification runs "
+                         "through the paged prefill-append kernel)")
+    cfg = build_config(args)
+    params = build_params(
+        cfg, log, decode_m=args.slots,
+        auto_block=getattr(args, "auto_block", False),
+        plan_fitness=getattr(args, "plan_fitness", "analytic"),
+        weight_dtype=args.weight_dtype)
+    draft_cfg, draft_params = None, None
+    if args.spec_k:
+        draft_cfg, draft_params = build_draft(cfg, args, log=log)
+    return InferenceEngine(cfg, params, EngineConfig(
+        n_slots=args.slots, capacity=args.capacity,
+        page_size=args.page_size, kv_pages=args.kv_pages or None,
+        prefix_cache=args.prefix_cache,
+        spec_k=args.spec_k, draft_cfg=draft_cfg,
+        kv_dtype=args.kv_dtype,
+        max_waiting=args.max_waiting or None,
+        preempt_after_stalls=args.preempt_after_stalls,
+        slo_admission=args.slo_admission, slo_slack=args.slo_slack,
+        mesh_model=args.mesh_model, **engine_kw),
+        draft_params=draft_params)
+
+
 def build_params(cfg: ModelConfig, log=print, *, decode_m: int = 8,
                  auto_block: bool = False,
                  plan_fitness: str = "analytic",
@@ -609,8 +703,11 @@ def main() -> None:
                    help="BCR block side; 0 → 16 for --smoke configs "
                         "(whose d_model is too small for the 128 default), "
                         "else the config default")
-    p.add_argument("--impl", default="ref",
-                   choices=["ref", "interpret", "pallas"])
+    p.add_argument("--impl", default=None,
+                   choices=["ref", "interpret", "pallas"],
+                   help="kernel backend (default: pallas on a TPU, ref "
+                        "elsewhere; ref is the pure-JAX oracle, interpret "
+                        "is CPU-only)")
     p.add_argument("--auto-block", action="store_true",
                    help="Listing-1 block-size search per layer geometry at "
                         "pack time instead of taking the config block")
@@ -637,19 +734,17 @@ def main() -> None:
                         "XLA_FLAGS=--xla_force_host_platform_device_count")
     p.add_argument("--json-out", default=None)
     args = p.parse_args()
-
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    cfg = dataclasses.replace(cfg, bcr_keep_frac=args.bcr_keep,
-                              kernel_impl=args.impl)
-    if args.bcr_block or args.smoke:
-        b = args.bcr_block or 16
-        cfg = dataclasses.replace(cfg, bcr_block=(b, b))
-    params = build_params(
-        cfg, decode_m=(args.batch if args.mode == "static" else args.slots),
-        auto_block=args.auto_block, plan_fitness=args.plan_fitness,
-        weight_dtype=args.weight_dtype)
+    enable_compile_cache()
 
     if args.mode == "static":
+        try:
+            cfg = build_config(args)
+        except ValueError as e:
+            p.error(str(e))
+        params = build_params(cfg, decode_m=args.batch,
+                              auto_block=args.auto_block,
+                              plan_fitness=args.plan_fitness,
+                              weight_dtype=args.weight_dtype)
         if args.kv_dtype:
             cfg = dataclasses.replace(cfg, kv_dtype=args.kv_dtype)
         generate(cfg, params, ServeConfig(batch=args.batch,
@@ -658,25 +753,10 @@ def main() -> None:
                                           capacity=args.capacity))
         return
 
-    if args.prefix_cache and not args.page_size:
-        p.error("--prefix-cache needs --page-size (paged KV pool)")
-    if args.spec_k and not args.page_size:
-        p.error("--spec-k needs --page-size (verification runs through "
-                "the paged prefill-append kernel)")
-    draft_cfg, draft_params = None, None
-    if args.spec_k:
-        draft_cfg, draft_params = build_draft(cfg, args, log=print)
-    engine = InferenceEngine(cfg, params, EngineConfig(
-        n_slots=args.slots, capacity=args.capacity,
-        page_size=args.page_size, kv_pages=args.kv_pages or None,
-        prefix_cache=args.prefix_cache,
-        spec_k=args.spec_k, draft_cfg=draft_cfg,
-        kv_dtype=args.kv_dtype,
-        max_waiting=args.max_waiting or None,
-        preempt_after_stalls=args.preempt_after_stalls,
-        slo_admission=args.slo_admission, slo_slack=args.slo_slack,
-        mesh_model=args.mesh_model),
-        draft_params=draft_params)
+    try:
+        engine = build_engine(args)
+    except ValueError as e:
+        p.error(str(e))
     # mixed prompt lengths around --prompt-len, clamped so every request
     # fits its slot (prompt + gen + spec headroom ≤ capacity;
     # shared-prefix workloads also carry --system-len tokens per prompt)

@@ -134,8 +134,9 @@ def _mixer_cache_init(kind: str, cfg: ModelConfig, batch: int, capacity: int,
             # null sink for pad/inactive writes). kv_dtype="int8" stores
             # quantized codes plus sibling per-row-per-head scale pools
             # that share the page index space (so page copies / frees /
-            # table lookups cover data and scales together).
-            shape = (kv_pages, page_size, cfg.num_kv_heads, cfg.head_dim)
+            # table lookups cover data and scales together). Head-major,
+            # so a (page, kv-head) kernel block is a full (page_size, D).
+            shape = (kv_pages, cfg.num_kv_heads, page_size, cfg.head_dim)
             c = {"k": jnp.zeros(shape, kv_dt), "v": jnp.zeros(shape, kv_dt)}
             if quant:
                 c["k_scale"] = jnp.zeros(shape[:-1], jnp.float32)
@@ -347,7 +348,7 @@ def init_cache(cfg: ModelConfig, batch: int, capacity: int, *,
     """Decode cache pytree, stacked to mirror the param layout.
 
     With ``page_size > 0`` attention K/V leaves become a shared page pool
-    ``(kv_pages, page_size, Hkv, D)`` per layer (block tables supplied to
+    ``(kv_pages, Hkv, page_size, D)`` per layer (block tables supplied to
     ``decode_step`` map slots onto pages); recurrent-state leaves keep the
     per-slot batch layout either way.
     """

@@ -340,26 +340,27 @@ def paged_prefill_append_dispatch(q, k_pages, v_pages, block_tables,
 
 
 def _paged_write(pages, scale_pool, dest, rows, n_kv, head_dim):
-    """Scatter K/V rows into a page pool at flat row positions ``dest``.
+    """Scatter K/V rows into a head-major page pool ``(n_pages, Hkv,
+    page_size, D)`` at flat row positions ``dest = page * page_size +
+    offset``.
 
     Unquantized pools store ``rows`` cast to the pool dtype. int8 pools
     (``scale_pool`` not None) quantize on store: each row gets a per-kv-
-    head symmetric scale written into the sibling ``(n_pages, page_size,
-    Hkv)`` scale pool at the same flat position, so the pool and its
+    head symmetric scale written into the sibling ``(n_pages, Hkv,
+    page_size)`` scale pool at the same position, so the pool and its
     scales can never drift apart (CoW copies, truncation and eviction all
     move them together). Returns ``(pages, scale_pool)``.
     """
-    flat = (-1, n_kv, head_dim)
+    page_size = pages.shape[2]
+    pid, off = dest // page_size, dest % page_size
+    rows = rows.reshape(-1, n_kv, head_dim)
     if scale_pool is None:
-        pages = pages.reshape(flat).at[dest].set(
-            rows.reshape(flat).astype(pages.dtype)).reshape(pages.shape)
-        return pages, None
+        return pages.at[pid, :, off].set(rows.astype(pages.dtype)), None
     from repro.kernels.quant import quantize_rows
-    codes, scales = quantize_rows(rows.reshape(flat))
-    pages = pages.reshape(flat).at[dest].set(codes).reshape(pages.shape)
-    sshape = scale_pool.shape
-    scale_pool = scale_pool.reshape(-1, n_kv).at[dest].set(
-        scales.astype(scale_pool.dtype)).reshape(sshape)
+    codes, scales = quantize_rows(rows)
+    pages = pages.at[pid, :, off].set(codes)
+    scale_pool = scale_pool.at[pid, :, off].set(
+        scales.astype(scale_pool.dtype))
     return pages, scale_pool
 
 
@@ -384,7 +385,7 @@ def attention_apply(
     stream stays replicated).
 
     With ``block_tables`` the cache leaves are a shared page pool
-    ``(n_pages, page_size, Hkv, D)`` instead of per-slot capacity rows:
+    ``(n_pages, Hkv, page_size, D)`` instead of per-slot capacity rows:
     the step's K/V scatter into each slot's current page and attention
     reads only table pages (see kernels/paged_decode_attention.py).
     ``s > 1`` with a paged cache is the prefill-append path: ``cache_len``
@@ -407,7 +408,7 @@ def attention_apply(
         slen = jnp.asarray(suffix_len)
         ck, cv = cache["k"], cache["v"]
         ks_pool, vs_pool = cache.get("k_scale"), cache.get("v_scale")
-        page_size = ck.shape[1]
+        page_size = ck.shape[2]
         n_cols = block_tables.shape[1]
         pos = plen[:, None] + jnp.arange(s)[None]            # (B, S)
         valid = jnp.arange(s)[None] < slen[:, None]
@@ -430,7 +431,7 @@ def attention_apply(
         idx = jnp.asarray(cache_len)
         ck, cv = cache["k"], cache["v"]
         ks_pool, vs_pool = cache.get("k_scale"), cache.get("v_scale")
-        n_pages, page_size = ck.shape[0], ck.shape[1]
+        page_size = ck.shape[2]
         dest = (jnp.take_along_axis(
             block_tables, (idx // page_size)[:, None], axis=1)[:, 0]
             * page_size + idx % page_size)

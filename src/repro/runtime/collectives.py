@@ -19,7 +19,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 PyTree = Any
 
@@ -51,7 +51,7 @@ def hierarchical_psum(grads: PyTree, mesh: Mesh, *, codec: Optional[str] = None
         spec = P()
         return shard_map(
             f, mesh=mesh, in_specs=spec, out_specs=spec,
-            check_rep=False)(g)
+            check_vma=False)(g)
 
     return jax.tree_util.tree_map(reduce_one, grads)
 

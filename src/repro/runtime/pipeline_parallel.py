@@ -20,7 +20,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 PyTree = Any
 
@@ -92,7 +92,7 @@ def pipeline_apply(
         per_device, mesh=mesh,
         in_specs=(param_spec, P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, x)
 
 

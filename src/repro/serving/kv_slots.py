@@ -18,7 +18,7 @@ Two pools share that probing trick:
 * :class:`SlotPool` — capacity-dense: every slot owns ``capacity`` cache
   rows whether it uses them or not.
 * :class:`PagedSlotPool` — block-paged: attention K/V leaves become a
-  shared page pool ``(n_pages, page_size, Hkv, D)`` plus per-slot block
+  shared page pool ``(n_pages, Hkv, page_size, D)`` plus per-slot block
   tables (physical page ids); pages are reserved at admission, allocated
   lazily as a slot's length crosses page boundaries, and returned on
   retirement. Slot count decouples from context capacity: provisioned HBM
@@ -463,21 +463,25 @@ class PagedSlotPool:
     def _write_fn(self, pool: PyTree, new: PyTree, dest: jax.Array,
                   slots: jax.Array) -> PyTree:
         """Paged leaves: one scatter of every (row, position) prefill entry
-        into its flat pool row ``table[row, pos // ps] * ps + pos % ps``
-        (pad rows / positions past a slot's pages carry table id 0 and land
-        in the null page). Slot leaves: reverse-order row writes as in
+        into its pool row ``dest = table[row, pos // ps] * ps + pos % ps``,
+        i.e. page ``dest // ps`` at in-page offset ``dest % ps`` (pad rows /
+        positions past a slot's pages carry table id 0 and land in the null
+        page). Slot leaves: reverse-order row writes as in
         :meth:`SlotPool._insert_rows_fn`."""
+        ps = self.page_size
+        pid, off = dest // ps, dest % ps
+
         def w(p, n, bax, pax):
             if pax >= 0:
-                # merge (n_pages, page_size) / (batch, seq) axis pairs: the
-                # pool's page axis sits where the prefill leaf's batch axis
-                # sits (both trees share the leading stacking dims), so one
-                # fancy-index set covers prefix and stack layouts
-                flat = p.reshape(p.shape[:pax] + (-1,) + p.shape[pax + 2:])
+                # the pool leaf is (..., n_pages, Hkv, page_size[, D]) and
+                # the prefill leaf (..., batch, seq, Hkv[, D]) with the same
+                # leading stacking dims: merge (batch, seq) into one row
+                # axis and move it first, where numpy-style indexing puts
+                # the (page, offset) index pair split by the Hkv slice
                 src = n.reshape(n.shape[:pax] + (-1,) + n.shape[pax + 2:])
-                idx = (slice(None),) * pax + (dest,)
-                flat = flat.at[idx].set(src.astype(p.dtype))
-                return flat.reshape(p.shape)
+                src = jnp.moveaxis(src, pax, 0)
+                idx = (slice(None),) * pax + (pid, slice(None), off)
+                return p.at[idx].set(src.astype(p.dtype))
             return p
 
         pool = jax.tree_util.tree_map(w, pool, new, self._batch_axes,

@@ -416,8 +416,11 @@ class InferenceServer:
         self.ready = False
         if self._server is not None:
             self._server.close()
-            await self._server.wait_closed()
+        # shed before waiting: since Python 3.12 ``wait_closed`` also waits
+        # for open connections, which would let the queue be admitted
         self.host.begin_drain()
+        if self._server is not None:
+            await self._server.wait_closed()
         deadline = time.monotonic() + self.sc.drain_grace_s
         while time.monotonic() < deadline:
             if (not self.engine.sched.has_work()

@@ -42,7 +42,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -321,9 +321,9 @@ def cache_specs(cfg: ModelConfig, batch: int, capacity: int, *,
     over the mesh (KV codes AND their int8 scale siblings — the scale
     leaf's own ``Hkv`` axis diffs in the same probe, so scales shard with
     their codes for free), everything else replicated. The same axis
-    indices serve both the persistent pool layout and the prefill-output
-    layout — both put ``Hkv`` at axis −2 of their k/v leaves, probed per
-    leaf rather than assumed."""
+    indices serve both the persistent head-major pool layout (``Hkv`` at
+    axis −3 of its k/v leaves) and the prefill-output layout (axis −2),
+    probed per leaf rather than assumed."""
     axes = cache_axes(cfg, batch, capacity, kv_pages=kv_pages,
                       page_size=page_size)
     return jax.tree_util.tree_map(
@@ -350,7 +350,7 @@ class ShardedProgram:
     value gets its own closed-over body + jit; the call-site keyword
     dispatches between them (compiles lazily, exactly like the
     single-device engine's two-variant static_argnames jit).
-    ``check_rep=False`` because replicated outputs (sampled tokens,
+    ``check_vma=False`` because replicated outputs (sampled tokens,
     logits after the lm_head gather) are replicated by construction —
     every shard computes the identical full array."""
 
@@ -362,7 +362,7 @@ class ShardedProgram:
         def build(**kw):
             body = functools.partial(fn, **kw) if kw else fn
             sm = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                           out_specs=out_specs, check_rep=False)
+                           out_specs=out_specs, check_vma=False)
             return jax.jit(sm, donate_argnums=donate_argnums)
 
         if static_name is None:

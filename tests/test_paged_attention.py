@@ -28,9 +28,9 @@ def _paged_case(lens, page_size, hkv=2, g=2, d=16, n_cols=None, seed=0):
         -(-int(l) // page_size) for l in lens) or 1
     n_pages = 1 + b * max_pages
     q = jnp.asarray(rng.normal(size=(b, 1, hkv * g, d)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(n_pages, page_size, hkv, d)),
+    kp = jnp.asarray(rng.normal(size=(n_pages, hkv, page_size, d)),
                      jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(n_pages, page_size, hkv, d)),
+    vp = jnp.asarray(rng.normal(size=(n_pages, hkv, page_size, d)),
                      jnp.float32)
     bt = np.zeros((b, max_pages), np.int32)
     pid = 1
@@ -41,8 +41,11 @@ def _paged_case(lens, page_size, hkv=2, g=2, d=16, n_cols=None, seed=0):
     lens = jnp.asarray(lens, jnp.int32)
     bt = jnp.asarray(bt)
     cap = max_pages * page_size
-    k_dense = jnp.take(kp, bt, axis=0).reshape(b, cap, hkv, d)
-    v_dense = jnp.take(vp, bt, axis=0).reshape(b, cap, hkv, d)
+    # head-major pages (B, cols, Hkv, ps, D) -> contiguous (B, cap, Hkv, D)
+    k_dense = jnp.swapaxes(jnp.take(kp, bt, axis=0), 2, 3) \
+        .reshape(b, cap, hkv, d)
+    v_dense = jnp.swapaxes(jnp.take(vp, bt, axis=0), 2, 3) \
+        .reshape(b, cap, hkv, d)
     return q, kp, vp, bt, lens, k_dense, v_dense
 
 
@@ -167,11 +170,13 @@ class TestPageAllocator:
         assert pool.lens[1] == 8 and pool._n_alloc[1] == 1
         pid = int(pool.table[1, 0])
         # the slot's page now holds the prefill K rows (stack leaf layout:
-        # (repeats, n_pages, page_size, Hkv, D))
+        # (repeats, n_pages, Hkv, page_size, D); prefill leaf (repeats,
+        # batch, seq, Hkv, D))
         leaf = jax.tree_util.tree_leaves(pool.cache)[0]
         src = jax.tree_util.tree_leaves(pcache)[0]
         np.testing.assert_allclose(np.asarray(leaf[:, pid]),
-                                   np.asarray(src[:, 0]), atol=1e-6)
+                                   np.asarray(jnp.swapaxes(src[:, 0], 1, 2)),
+                                   atol=1e-6)
 
 
 class TestPagedEngine:

@@ -37,9 +37,9 @@ def _append_case(totals, plens, page_size, hkv=2, g=2, d=16, seed=0):
     max_pages = max(-(-int(t) // page_size) for t in totals)
     n_pages = 1 + b * max_pages
     q = jnp.asarray(rng.normal(size=(b, s, hkv * g, d)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(n_pages, page_size, hkv, d)),
+    kp = jnp.asarray(rng.normal(size=(n_pages, hkv, page_size, d)),
                      jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(n_pages, page_size, hkv, d)),
+    vp = jnp.asarray(rng.normal(size=(n_pages, hkv, page_size, d)),
                      jnp.float32)
     bt = np.zeros((b, max_pages), np.int32)
     pid = 1
@@ -61,8 +61,11 @@ class TestPrefillAppendMath:
         q, kp, vp, bt, pl, tl = _append_case(totals, plens, page_size)
         ref = paged_prefill_append_ref(q, kp, vp, bt, pl, tl)
         cap = bt.shape[1] * page_size
-        kd = jnp.take(kp, bt, axis=0).reshape(len(totals), cap, 2, 16)
-        vd = jnp.take(vp, bt, axis=0).reshape(len(totals), cap, 2, 16)
+        # head-major pages -> contiguous (B, cap, Hkv, D) history
+        kd = jnp.swapaxes(jnp.take(kp, bt, axis=0), 2, 3) \
+            .reshape(len(totals), cap, 2, 16)
+        vd = jnp.swapaxes(jnp.take(vp, bt, axis=0), 2, 3) \
+            .reshape(len(totals), cap, 2, 16)
         for i, (t, p) in enumerate(zip(totals, plens)):
             sfx = t - p
             if sfx == 0:

@@ -72,9 +72,9 @@ def _quantized_paged_case(lens, page_size, hkv=2, g=4, d=64, seed=0):
     b = len(lens)
     n_cols = max(-(-int(l) // page_size) for l in lens) or 1
     n_pages = 1 + b * n_cols
-    kf = jnp.asarray(rng.normal(size=(n_pages, page_size, hkv, d)),
+    kf = jnp.asarray(rng.normal(size=(n_pages, hkv, page_size, d)),
                      jnp.float32)
-    vf = jnp.asarray(rng.normal(size=(n_pages, page_size, hkv, d)),
+    vf = jnp.asarray(rng.normal(size=(n_pages, hkv, page_size, d)),
                      jnp.float32)
     kc, ks = quantize_rows(kf)
     vc, vs = quantize_rows(vf)

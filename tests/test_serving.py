@@ -322,3 +322,29 @@ class TestRecurrentFamilies:
         assert not eng.pad_prefill   # recurrent state: exact-length prefill
         got = eng.generate(prompts, max_new_tokens=5)
         assert got == ref
+
+
+class TestSelectBackend:
+    """The serving CLIs' kernel backend comes from the platform; an
+    explicit ``--impl`` overrides it and impossible pairs are refused."""
+
+    @pytest.mark.parametrize("platform,impl,page_size,want", [
+        ("cpu", None, 16, ("ref", None)),
+        ("cpu", "interpret", 16, ("interpret", "paged_interpret")),
+        ("cpu", "interpret", 0, ("interpret", None)),
+        ("tpu", None, 16, ("pallas", "paged")),
+        ("tpu", None, 0, ("pallas", None)),
+        ("tpu", "ref", 16, ("ref", None)),
+    ])
+    def test_choice(self, monkeypatch, platform, impl, page_size, want):
+        from repro.launch.serve import select_backend
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        assert select_backend(impl, page_size) == want
+
+    @pytest.mark.parametrize("platform,impl", [("tpu", "interpret"),
+                                               ("cpu", "pallas")])
+    def test_refused(self, monkeypatch, platform, impl):
+        from repro.launch.serve import select_backend
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        with pytest.raises(ValueError):
+            select_backend(impl, 16)

@@ -171,8 +171,6 @@ class TestHloCost:
                              jax.ShapeDtypeStruct((64, 64), jnp.float32)).compile()
         mine = analyze(c.as_text())["flops"]
         ca = c.cost_analysis()
-        if isinstance(ca, (list, tuple)):   # newer jax: one dict per program
-            ca = ca[0]
         assert mine == pytest.approx(ca["flops"], rel=0.05)
 
     def test_scan_equals_unroll(self):
