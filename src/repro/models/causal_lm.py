@@ -250,7 +250,8 @@ def layer_apply(
 
     h2 = L.rmsnorm(lp["norm2"], x, cfg.norm_eps)
     if ffn_kind == "mlp":
-        out2 = L.swiglu_apply(lp["ffn"], h2, impl, tp_axis=cfg.tp_axis)
+        with jax.named_scope("projections"):
+            out2 = L.swiglu_apply(lp["ffn"], h2, impl, tp_axis=cfg.tp_axis)
     elif ffn_kind == "moe":
         out2 = MOE.moe_apply(lp["ffn"], h2, cfg, impl,
                              token_mask=token_mask)

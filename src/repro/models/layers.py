@@ -395,7 +395,9 @@ def attention_apply(
     through the pages.
     """
     b, s, _ = x.shape
-    q, k, v = _qkv(params, x, n_heads, n_kv, head_dim, positions, rope_theta, impl)
+    with jax.named_scope("projections"):
+        q, k, v = _qkv(params, x, n_heads, n_kv, head_dim, positions,
+                       rope_theta, impl)
 
     if cache is not None and block_tables is not None and s > 1:
         # prefill-append: scatter the suffix K/V rows into the slot's own
@@ -432,16 +434,18 @@ def attention_apply(
         ck, cv = cache["k"], cache["v"]
         ks_pool, vs_pool = cache.get("k_scale"), cache.get("v_scale")
         page_size = ck.shape[2]
-        dest = (jnp.take_along_axis(
-            block_tables, (idx // page_size)[:, None], axis=1)[:, 0]
-            * page_size + idx % page_size)
-        k_pages, ks_pool = _paged_write(ck, ks_pool, dest, k[:, 0],
-                                        n_kv, head_dim)
-        v_pages, vs_pool = _paged_write(cv, vs_pool, dest, v[:, 0],
-                                        n_kv, head_dim)
-        out = paged_decode_attention_dispatch(
-            q, k_pages, v_pages, block_tables, idx + 1, attn_impl,
-            k_scale=ks_pool, v_scale=vs_pool)
+        with jax.named_scope("kv_write"):
+            dest = (jnp.take_along_axis(
+                block_tables, (idx // page_size)[:, None], axis=1)[:, 0]
+                * page_size + idx % page_size)
+            k_pages, ks_pool = _paged_write(ck, ks_pool, dest, k[:, 0],
+                                            n_kv, head_dim)
+            v_pages, vs_pool = _paged_write(cv, vs_pool, dest, v[:, 0],
+                                            n_kv, head_dim)
+        with jax.named_scope("attention"):
+            out = paged_decode_attention_dispatch(
+                q, k_pages, v_pages, block_tables, idx + 1, attn_impl,
+                k_scale=ks_pool, v_scale=vs_pool)
         new_cache = {"k": k_pages, "v": v_pages}
         if ks_pool is not None:
             new_cache.update(k_scale=ks_pool, v_scale=vs_pool)
@@ -504,8 +508,10 @@ def attention_apply(
     if tp_axis:
         out = maybe_gather(out, _linear_in_dim(params["wo"]) // head_dim,
                            tp_axis, axis=2)
-    y = linear_apply(params["wo"], out.reshape(b, s, out.shape[2] * head_dim),
-                     impl=impl)
+    with jax.named_scope("projections"):
+        y = linear_apply(params["wo"],
+                         out.reshape(b, s, out.shape[2] * head_dim),
+                         impl=impl)
     y = maybe_gather(y, x.shape[-1], tp_axis)
     return y, new_cache
 

@@ -47,6 +47,13 @@ target's own distribution. Rejected drafts rewind: the pool truncates
 back to the committed frontier and tail pages return to the slot's
 reservation (they were allocated this step and never shared/registered).
 
+Tracing: each step records host spans (``serving/tracing.py``) while a
+profiler session runs — ``engine.step`` around ``engine.admit``,
+``engine.prefill`` per admission group, ``engine.decode.dispatch``,
+``engine.decode.wait`` and ``engine.commit`` (or ``engine.spec``) — with
+the step's live rows, context tokens and KV-page gauges as args; see
+``docs/serving.md`` ("Observability").
+
 Thread safety: one reentrant engine lock guards every scheduler/pool
 mutation (``submit`` / ``cancel`` / ``step`` / ``reset_stats`` and the
 drain/recover hooks), so an asyncio HTTP front-end (``serving/server.py``)
@@ -81,6 +88,7 @@ from repro.serving.admission import (TenantQuota, TokenBucket,
 from repro.serving.faults import StepWatchdog
 from repro.serving.kv_slots import PagedSlotPool, SlotPool
 from repro.serving.tp import per_device_kv_bytes
+from repro.serving.tracing import span
 from repro.serving.scheduler import (CANCELLED, FAILED, FINISHED, REJECTED,
                                      TIMEOUT, Request, Scheduler)
 
@@ -383,9 +391,10 @@ class InferenceEngine:
                 p, {"tokens": toks, "cache_len": lens,
                     "block_tables": bt,
                     "token_mask": (lens > 0)[:, None]}, cache)
-            last = logits[:, -1]
-            ok = jnp.isfinite(last).all(axis=-1)
-            tok = sample_tokens(last, key, temps, topks, use_topk)
+            with jax.named_scope("sample"):
+                last = logits[:, -1]
+                ok = jnp.isfinite(last).all(axis=-1)
+                tok = sample_tokens(last, key, temps, topks, use_topk)
             return tok, ok, cache
 
         def append_sample(p, toks, plen, slen, cache, bt, key, temps,
@@ -934,7 +943,6 @@ class InferenceEngine:
             req.generated.append(tok)
             req.token_times.append(now)
             self._tokens[slot, 0] = tok
-            self.stats["tokens_generated"] += 1
             if self.prefix_cache:
                 self.pool.register_prefix(slot, req.prompt)
             alive.append((req, slot))
@@ -955,30 +963,32 @@ class InferenceEngine:
         positions/rows out of MoE expert capacity."""
         k = len(group)
         bucket = max(self._bucket(req.prompt_len) for req, _ in group)
-        k_pad = next(t for t in self._row_tiers() if t >= k)
-        toks = np.zeros((k_pad, bucket), np.int32)
-        lens = np.ones((k_pad,), np.int32)
-        mask = np.zeros((k_pad, bucket), bool)
-        temps = np.zeros((k_pad,), np.float32)
-        topks = np.zeros((k_pad,), np.int32)
-        slots = np.zeros((k_pad,), np.int32)
-        for i, (req, slot) in enumerate(group):
-            p = req.prompt_len
-            toks[i, :p] = req.prompt
-            lens[i] = p
-            mask[i, :p] = True
-            temps[i] = req.temperature
-            topks[i] = req.top_k
-            slots[i] = slot
-        slots[k:] = slots[0]
-        tok_dev, ok_dev, pcache = self._prefill(
-            self.params, jnp.asarray(toks), jnp.asarray(lens),
-            jnp.asarray(mask), self._next_key(), jnp.asarray(temps),
-            jnp.asarray(topks), use_topk=bool(topks.any()))
-        self.pool.insert_rows(pcache, slots, lens[:k])
-        self.stats["prefills"] += 1
-        self.stats["prefill_rows"] += k
-        return self._finish_admission(group, tok_dev, ok_dev)
+        with span("engine.prefill", rows=k, width=bucket,
+                  rids=";".join(str(req.rid) for req, _ in group)):
+            k_pad = next(t for t in self._row_tiers() if t >= k)
+            toks = np.zeros((k_pad, bucket), np.int32)
+            lens = np.ones((k_pad,), np.int32)
+            mask = np.zeros((k_pad, bucket), bool)
+            temps = np.zeros((k_pad,), np.float32)
+            topks = np.zeros((k_pad,), np.int32)
+            slots = np.zeros((k_pad,), np.int32)
+            for i, (req, slot) in enumerate(group):
+                p = req.prompt_len
+                toks[i, :p] = req.prompt
+                lens[i] = p
+                mask[i, :p] = True
+                temps[i] = req.temperature
+                topks[i] = req.top_k
+                slots[i] = slot
+            slots[k:] = slots[0]
+            tok_dev, ok_dev, pcache = self._prefill(
+                self.params, jnp.asarray(toks), jnp.asarray(lens),
+                jnp.asarray(mask), self._next_key(), jnp.asarray(temps),
+                jnp.asarray(topks), use_topk=bool(topks.any()))
+            self.pool.insert_rows(pcache, slots, lens[:k])
+            self.stats["prefills"] += 1
+            self.stats["prefill_rows"] += k
+            return self._finish_admission(group, tok_dev, ok_dev)
 
     def _admit_group_append(self, group: List) -> List[Request]:
         """ONE prefill-append dispatch for a batch of prefix-hit
@@ -992,58 +1002,60 @@ class InferenceEngine:
         k = len(group)
         bucket = max(self._bucket(req.prompt_len - req.prefix_hit)
                      for req, _ in group)
-        k_pad = next(t for t in self._row_tiers() if t >= k)
-        toks = np.zeros((k_pad, bucket), np.int32)
-        plens = np.zeros((k_pad,), np.int32)
-        slens = np.ones((k_pad,), np.int32)
-        temps = np.zeros((k_pad,), np.float32)
-        topks = np.zeros((k_pad,), np.int32)
-        slots = np.zeros((k_pad,), np.int32)
-        cow: List = []
-        for i, (req, slot) in enumerate(group):
-            hit, p = req.prefix_hit, req.prompt_len
-            toks[i, :p - hit] = req.prompt[hit:]
-            plens[i] = hit
-            slens[i] = p - hit
-            temps[i] = req.temperature
-            topks[i] = req.top_k
-            slots[i] = slot
-            if hit % self.pool.page_size:
-                # the suffix starts inside a shared (adopted partial
-                # final) page — materialize a private copy first
-                pair = self.pool.ensure_writable(slot, hit)
-                if pair is not None:
-                    cow.append(pair)
-            self.pool.ensure(slot, p)     # suffix pages before the scatter
-        if cow:
-            src, dst = zip(*cow)
-            self.pool.copy_pages(np.asarray(src), np.asarray(dst))
-        # pow2-bucketed table width, like decode's live-width bucketing:
-        # the kernel grid is (B, Hkv, n_cols), so a full-width table made
-        # every admission sweep max_pages grid steps per slot even when
-        # the longest prompt covered a handful of pages. The bucket covers
-        # the widest member's prompt pages; warmup compiles the append
-        # program per (suffix bucket × row tier × width).
-        need = max(self.pool.pages_needed(req.prompt_len)
-                   for req, _ in group)
-        w = 1
-        while w < need:
-            w *= 2
-        w = min(w, self.pool.max_pages)
-        bt = np.zeros((k_pad, w), np.int32)
-        bt[:k] = self.pool.table[slots[:k], :w]
-        tok_dev, ok_dev, self.pool.cache = self._append(
-            self.params, jnp.asarray(toks), jnp.asarray(plens),
-            jnp.asarray(slens), self.pool.cache, jnp.asarray(bt),
-            self._next_key(), jnp.asarray(temps), jnp.asarray(topks),
-            use_topk=bool(topks.any()))
-        for i, (req, slot) in enumerate(group):
-            self.pool.lens[slot] = req.prompt_len
-        self.stats["prefills"] += 1
-        self.stats["prefill_rows"] += k
-        self.stats["prefix_hit_tokens"] += int(sum(r.prefix_hit
-                                                   for r, _ in group))
-        return self._finish_admission(group, tok_dev, ok_dev)
+        with span("engine.prefill", rows=k, width=bucket,
+                  rids=";".join(str(req.rid) for req, _ in group)):
+            k_pad = next(t for t in self._row_tiers() if t >= k)
+            toks = np.zeros((k_pad, bucket), np.int32)
+            plens = np.zeros((k_pad,), np.int32)
+            slens = np.ones((k_pad,), np.int32)
+            temps = np.zeros((k_pad,), np.float32)
+            topks = np.zeros((k_pad,), np.int32)
+            slots = np.zeros((k_pad,), np.int32)
+            cow: List = []
+            for i, (req, slot) in enumerate(group):
+                hit, p = req.prefix_hit, req.prompt_len
+                toks[i, :p - hit] = req.prompt[hit:]
+                plens[i] = hit
+                slens[i] = p - hit
+                temps[i] = req.temperature
+                topks[i] = req.top_k
+                slots[i] = slot
+                if hit % self.pool.page_size:
+                    # the suffix starts inside a shared (adopted partial
+                    # final) page — materialize a private copy first
+                    pair = self.pool.ensure_writable(slot, hit)
+                    if pair is not None:
+                        cow.append(pair)
+                self.pool.ensure(slot, p)     # suffix pages before the scatter
+            if cow:
+                src, dst = zip(*cow)
+                self.pool.copy_pages(np.asarray(src), np.asarray(dst))
+            # pow2-bucketed table width, like decode's live-width bucketing:
+            # the kernel grid is (B, Hkv, n_cols), so a full-width table made
+            # every admission sweep max_pages grid steps per slot even when
+            # the longest prompt covered a handful of pages. The bucket covers
+            # the widest member's prompt pages; warmup compiles the append
+            # program per (suffix bucket × row tier × width).
+            need = max(self.pool.pages_needed(req.prompt_len)
+                       for req, _ in group)
+            w = 1
+            while w < need:
+                w *= 2
+            w = min(w, self.pool.max_pages)
+            bt = np.zeros((k_pad, w), np.int32)
+            bt[:k] = self.pool.table[slots[:k], :w]
+            tok_dev, ok_dev, self.pool.cache = self._append(
+                self.params, jnp.asarray(toks), jnp.asarray(plens),
+                jnp.asarray(slens), self.pool.cache, jnp.asarray(bt),
+                self._next_key(), jnp.asarray(temps), jnp.asarray(topks),
+                use_topk=bool(topks.any()))
+            for i, (req, slot) in enumerate(group):
+                self.pool.lens[slot] = req.prompt_len
+            self.stats["prefills"] += 1
+            self.stats["prefill_rows"] += k
+            self.stats["prefix_hit_tokens"] += int(sum(r.prefix_hit
+                                                       for r, _ in group))
+            return self._finish_admission(group, tok_dev, ok_dev)
 
     def _should_admit(self) -> bool:
         """Chunked-backfill hysteresis: batch steady-state admissions into
@@ -1059,7 +1071,6 @@ class InferenceEngine:
         if self._defer_steps >= self.ec.backfill_max_defer:
             return True
         self._defer_steps += 1
-        self.stats["deferred_admissions"] += 1
         return False
 
     def step(self) -> List[Request]:
@@ -1069,11 +1080,115 @@ class InferenceEngine:
         the whole iteration: cross-thread submit/cancel callers serialize
         against it (they block at most one step)."""
         with self._elock:
-            return self._step()
+            self._step_idx += 1
+            with span("engine.step", step=self._step_idx) as sp:
+                return self._step(sp)
 
-    def _step(self) -> List[Request]:
-        self._step_idx += 1
+    def _step(self, sp) -> List[Request]:
         t_step = self._clock()
+        with span("engine.admit") as sa:
+            finished, admitted, stalled = self._admit()
+            sa.set_metadata(admitted=len(admitted), stalled=int(stalled))
+        if sp.is_enabled():
+            sp.set_metadata(live=len(self.sched.active),
+                            waiting=len(self.sched.waiting),
+                            **(self.pool.page_gauges() if self.paged
+                               else {}))
+        if admitted:
+            self._defer_steps = 0
+            hits = [(r, s) for r, s in admitted if r.prefix_hit > 0]
+            cold = [(r, s) for r, s in admitted if r.prefix_hit == 0]
+            if hits:
+                # prefix-hit admissions share ONE suffix-only dispatch
+                finished.extend(self._admit_group_append(hits))
+            if cold and self.pad_prefill:
+                # padded families: ONE merged dispatch for the whole batch
+                # of admissions, whatever their prompt lengths
+                finished.extend(self._admit_group(cold))
+            elif cold:
+                # recurrent families prefill at exact length (pads would
+                # advance the state) — group by exact prompt length
+                groups: Dict[int, List] = {}
+                for req, slot in cold:
+                    groups.setdefault(req.prompt_len, []).append((req, slot))
+                for group in groups.values():
+                    finished.extend(self._admit_group(group))
+
+        # requests whose first (prefill-sampled) token already completed them
+        for slot, req in list(self.sched.active.items()):
+            if req.is_finished():
+                self._release(slot)
+                finished.append(self.sched.retire(slot))
+        if not self.sched.active:
+            self._finish_step(t_step)
+            return finished
+
+        self.stats["slot_occupancy"].append(len(self.sched.active))
+        if self.spec:
+            with span("engine.spec"):
+                finished.extend(self._spec_step())
+            self._finish_step(t_step)
+            return finished
+        # each live slot writes one K/V row and attends over len + 1
+        write_lens = {slot: int(self.pool.lens[slot]) + 1
+                      for slot in self.sched.active}
+        with span("engine.decode.dispatch", step=self._step_idx,
+                  rows=len(write_lens), ctx_tokens=sum(write_lens.values())):
+            if self.paged:
+                bt = self._prepare_paged_writes(write_lens, extra=1)
+            else:
+                bt = None
+                rows = self.ec.n_slots * self.ec.capacity
+                self.stats["kv_bytes_read"] += rows * self._kv_row_bytes
+                self.stats["kv_bytes_read_live"] += rows * self._kv_row_bytes
+                self.stats["kv_bytes_read_device"] += per_device_kv_bytes(
+                    rows * self._kv_row_bytes, self.tp)
+            tok_dev, ok_dev, self.pool.cache = self._decode(
+                self.params, jnp.asarray(self._tokens),
+                jnp.asarray(self.pool.lens), self.pool.cache,
+                self._next_key(), jnp.asarray(self._temps),
+                jnp.asarray(self._topks), bt,
+                use_topk=bool(self._topks.any()))
+        with span("engine.decode.wait"):
+            next_tok = np.asarray(tok_dev)
+            ok = np.array(ok_dev)  # writable: the fault hook may flip a row
+        with span("engine.commit"):
+            faults = self.faults
+            if faults is not None and faults.fires(self._step_idx,
+                                                   "nan_logits"):
+                slots_live = sorted(self.sched.active)
+                victim = slots_live[faults.choose(len(slots_live))]
+                faults.record(self._step_idx, "nan_logits", victim)
+                ok[victim] = False
+            now = self._clock()
+            self.stats["decode_steps"] += 1
+
+            for slot, req in list(self.sched.active.items()):
+                if not ok[slot]:
+                    # containment: fail ONLY the poisoned row — its token
+                    # is garbage, so nothing is emitted and the slot retires
+                    req.error = "non-finite logits at decode"
+                    self._release(slot)
+                    finished.append(self.sched.retire(slot, FAILED))
+                    self.stats["failed"] += 1
+                    continue
+                tok = int(next_tok[slot])
+                req.generated.append(tok)
+                req.token_times.append(now)
+                self.pool.advance(slot)
+                self._tokens[slot, 0] = tok
+                if req.is_finished():
+                    self._release(slot)
+                    finished.append(self.sched.retire(slot))
+            self._finish_step(t_step)
+        return finished
+
+    def _admit(self) -> Tuple[List[Request], List, bool]:
+        """The step's admission phase: fault hooks, deadline expiry, the
+        scheduler's pick, page reservation and page-pressure preemption.
+        Returns the requests that reached a terminal status, the admitted
+        ``(req, slot)`` pairs, and whether the FCFS head stalled on
+        pages."""
         finished: List[Request] = []
         faults = self.faults
         if faults is not None:
@@ -1154,86 +1269,7 @@ class InferenceEngine:
                 self._stall_steps = 0
         elif admitted or not self.sched.waiting:
             self._stall_steps = 0
-        if admitted:
-            self._defer_steps = 0
-            hits = [(r, s) for r, s in admitted if r.prefix_hit > 0]
-            cold = [(r, s) for r, s in admitted if r.prefix_hit == 0]
-            if hits:
-                # prefix-hit admissions share ONE suffix-only dispatch
-                finished.extend(self._admit_group_append(hits))
-            if cold and self.pad_prefill:
-                # padded families: ONE merged dispatch for the whole batch
-                # of admissions, whatever their prompt lengths
-                finished.extend(self._admit_group(cold))
-            elif cold:
-                # recurrent families prefill at exact length (pads would
-                # advance the state) — group by exact prompt length
-                groups: Dict[int, List] = {}
-                for req, slot in cold:
-                    groups.setdefault(req.prompt_len, []).append((req, slot))
-                for group in groups.values():
-                    finished.extend(self._admit_group(group))
-
-        # requests whose first (prefill-sampled) token already completed them
-        for slot, req in list(self.sched.active.items()):
-            if req.is_finished():
-                self._release(slot)
-                finished.append(self.sched.retire(slot))
-        if not self.sched.active:
-            self._finish_step(t_step)
-            return finished
-
-        self.stats["slot_occupancy"].append(len(self.sched.active))
-        if self.spec:
-            finished.extend(self._spec_step())
-            self._finish_step(t_step)
-            return finished
-        if self.paged:
-            bt = self._prepare_paged_writes(
-                {slot: int(self.pool.lens[slot]) + 1
-                 for slot in self.sched.active}, extra=1)
-        else:
-            bt = None
-            rows = self.ec.n_slots * self.ec.capacity
-            self.stats["kv_bytes_read"] += rows * self._kv_row_bytes
-            self.stats["kv_bytes_read_live"] += rows * self._kv_row_bytes
-            self.stats["kv_bytes_read_device"] += per_device_kv_bytes(
-                rows * self._kv_row_bytes, self.tp)
-        tok_dev, ok_dev, self.pool.cache = self._decode(
-            self.params, jnp.asarray(self._tokens),
-            jnp.asarray(self.pool.lens), self.pool.cache,
-            self._next_key(), jnp.asarray(self._temps),
-            jnp.asarray(self._topks), bt, use_topk=bool(self._topks.any()))
-        next_tok = np.asarray(tok_dev)
-        ok = np.array(ok_dev)      # writable: the fault hook may flip a row
-        if faults is not None and faults.fires(self._step_idx, "nan_logits"):
-            slots_live = sorted(self.sched.active)
-            victim = slots_live[faults.choose(len(slots_live))]
-            faults.record(self._step_idx, "nan_logits", victim)
-            ok[victim] = False
-        now = self._clock()
-        self.stats["decode_steps"] += 1
-
-        for slot, req in list(self.sched.active.items()):
-            if not ok[slot]:
-                # containment: fail ONLY the poisoned row — its token is
-                # garbage, so nothing is emitted and the slot retires
-                req.error = "non-finite logits at decode"
-                self._release(slot)
-                finished.append(self.sched.retire(slot, FAILED))
-                self.stats["failed"] += 1
-                continue
-            tok = int(next_tok[slot])
-            req.generated.append(tok)
-            req.token_times.append(now)
-            self.pool.advance(slot)
-            self._tokens[slot, 0] = tok
-            self.stats["tokens_generated"] += 1
-            if req.is_finished():
-                self._release(slot)
-                finished.append(self.sched.retire(slot))
-        self._finish_step(t_step)
-        return finished
+        return finished, admitted, stalled
 
     def _finish_step(self, t_start: float) -> None:
         """End-of-step bookkeeping shared by every return path: mirror pool
@@ -1452,7 +1488,6 @@ class InferenceEngine:
             for tok in props[:a] + [follow]:
                 req.generated.append(int(tok))
                 req.token_times.append(now)
-                self.stats["tokens_generated"] += 1
                 committed += 1
                 if req.is_finished():
                     break
@@ -1478,7 +1513,6 @@ class InferenceEngine:
         with self._elock:
             self.stats.clear()
             self.stats.update(decode_steps=0, prefills=0, prefill_rows=0,
-                              deferred_admissions=0, tokens_generated=0,
                               page_stalls=0, kv_bytes_read=0,
                               kv_bytes_read_live=0, kv_bytes_read_device=0,
                               slot_occupancy=[],
@@ -1502,14 +1536,18 @@ class InferenceEngine:
                 if self.ec.watchdog_threshold > 0 else None)
             if self.paged:
                 self.pool.reset_stats()
+                self._sync_pool_stats()
 
     def _sync_pool_stats(self) -> None:
         """Mirror the allocator's counters (they tick deep inside page
-        allocation / CoW) into the reported stats dict — the pool is the
-        single source of truth for page-level events."""
+        allocation / CoW) and its page gauges (``kv_pages_in_use``,
+        ``kv_pages_reserved``, ``kv_pages_free``) into the reported stats
+        dict — the pool is the single source of truth for page-level
+        events."""
         if self.paged:
             for key in ("evictions", "pages_allocated", "cow_copies"):
                 self.stats[key] = self.pool.stats[key]
+            self.stats.update(self.pool.page_gauges())
 
     def warmup(self, prompt_lens: Sequence[int], gen: int = 2,
                suffix_lens: Optional[Sequence[int]] = None) -> None:

@@ -259,6 +259,15 @@ class PagedSlotPool:
         reservations. Maintained as O(1) counters — no per-slot rescans."""
         return len(self._free) + len(self._lru) - self._reserved_total
 
+    def page_gauges(self) -> Dict[str, int]:
+        """The pool's state in three O(1) gauges that sum to
+        ``n_pages - 1`` (the null page aside): pages some slot holds (each
+        counted once however many slots share it), pages reserved at
+        admission but not yet allocated, and pages allocatable now."""
+        return {"kv_pages_in_use": self.n_pages - 1 - self.idle_pages(),
+                "kv_pages_reserved": self._reserved_total,
+                "kv_pages_free": self.free_pages()}
+
     def pages_needed(self, total_len: int) -> int:
         return -(-total_len // self.page_size)
 

@@ -85,6 +85,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 from repro.serving.engine import InferenceEngine
 from repro.serving.scheduler import (CANCELLED, FAILED, FINISHED, REJECTED,
                                      TIMEOUT)
+from repro.serving.tracing import span
 
 #: terminal Request.status → HTTP status code. CANCELLED's 499 (client
 #: closed request, nginx convention) is bookkeeping only: by definition
@@ -249,7 +250,8 @@ class EngineHost:
                 # still runs (a draining client can un-pause its request)
                 self.engine.reap()
                 self._pump()
-                self._wake.wait(self.sc.idle_sleep_s)
+                with span("server.idle"):
+                    self._wake.wait(self.sc.idle_sleep_s)
                 self._wake.clear()
                 continue
             faults = self.engine.faults
@@ -295,9 +297,12 @@ class EngineHost:
         ``stream_queue_max`` triggers the slow-client policy; a paused
         stream resumes once depth drains to half the high-water mark.
         Depth can overshoot the mark by at most one step's token commit
-        (spec_k + 1), since the policy runs after every step."""
+        (spec_k + 1), since the policy runs after every step. Recorded as
+        the host span ``server.pump`` (args: open ``streams``, ``tokens``
+        pushed)."""
         hw = self.sc.stream_queue_max
-        with self._lock:
+        pushed = 0
+        with span("server.pump") as sp, self._lock:
             now = time.monotonic()
             _, live, fin = self.engine.poll(trim=True)
             for rid, toks in live:
@@ -305,7 +310,7 @@ class EngineHost:
                 if sub is None:
                     continue
                 if now >= sub.stall_until:
-                    self._push(sub, toks)
+                    pushed += self._push(sub, toks)
                 depth = sub.q.qsize() + (len(toks) - sub.emitted)
                 if depth > self.max_stream_depth:
                     self.max_stream_depth = depth
@@ -321,8 +326,9 @@ class EngineHost:
                 sub = self._subs.pop(rid, None)
                 if sub is None:
                     continue
-                self._push(sub, toks)
+                pushed += self._push(sub, toks)
                 self._send(sub, ("done", status, error, retry_after))
+            sp.set_metadata(streams=len(self._subs), tokens=pushed)
 
     def _backpressure(self, rid: int, sub: _Sub) -> None:
         """Apply the slow-client policy to one over-watermark stream.
@@ -340,14 +346,17 @@ class EngineHost:
             self.engine.cancel(rid)
 
     @staticmethod
-    def _push(sub: _Sub, toks: List[int]) -> None:
+    def _push(sub: _Sub, toks: List[int]) -> int:
+        """Queue the stream's undelivered tokens; returns how many."""
+        n = len(toks) - sub.emitted
         for tok in toks[sub.emitted:]:
             try:
                 sub.loop.call_soon_threadsafe(sub.q.put_nowait,
                                               ("token", tok))
             except RuntimeError:       # loop already closed (shutdown race)
-                return
+                return 0
         sub.emitted = len(toks)
+        return n
 
     @staticmethod
     def _send(sub: _Sub, item: Tuple) -> None:
