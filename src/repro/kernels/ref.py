@@ -155,7 +155,7 @@ def paged_decode_attention_ref(q: jax.Array, k_pages: jax.Array,
     int8 codes dequantized off the ``(n_pages, Hkv, page_size)`` scale
     pools after the gather. Bytes read scale with the table WIDTH handed
     in (the engine buckets it to the longest live slot) — the Pallas
-    kernel further drops per-slot dead columns via its index-map clamp.
+    kernel further skips each slot's dead columns (never copied).
     """
     b, s, h, d = q.shape
     assert s == 1

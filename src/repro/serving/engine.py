@@ -51,7 +51,8 @@ Tracing: each step records host spans (``serving/tracing.py``) while a
 profiler session runs — ``engine.step`` around ``engine.admit``,
 ``engine.prefill`` per admission group, ``engine.decode.dispatch``,
 ``engine.decode.wait`` and ``engine.commit`` (or ``engine.spec``) — with
-the step's live rows, context tokens and KV-page gauges as args; see
+the step's live rows, context tokens, KV-page gauges and the paged
+attention kernel's grid and live steps as args; see
 ``docs/serving.md`` ("Observability").
 
 Thread safety: one reentrant engine lock guards every scheduler/pool
@@ -1133,9 +1134,12 @@ class InferenceEngine:
         write_lens = {slot: int(self.pool.lens[slot]) + 1
                       for slot in self.sched.active}
         with span("engine.decode.dispatch", step=self._step_idx,
-                  rows=len(write_lens), ctx_tokens=sum(write_lens.values())):
+                  rows=len(write_lens),
+                  ctx_tokens=sum(write_lens.values())) as sd:
             if self.paged:
                 bt = self._prepare_paged_writes(write_lens, extra=1)
+                if sd.is_enabled():
+                    sd.set_metadata(**self._attn_steps(bt.shape[1]))
             else:
                 bt = None
                 rows = self.ec.n_slots * self.ec.capacity
@@ -1401,6 +1405,21 @@ class InferenceEngine:
         self.stats["kv_bytes_read_device"] += per_device_kv_bytes(
             step_bytes, self.tp)
         return bt
+
+    def _attn_steps(self, n_cols: int) -> Dict[str, int]:
+        """Grid steps per layer of this decode's ``paged_attention`` call
+        over a table ``n_cols`` wide, and those that copy a live page:
+        every slot attends over ``len + 1`` positions (a free slot's one
+        position lies in the null page), from the kernel's own block-size
+        rule."""
+        from repro.kernels.paged_decode_attention import attention_grid_steps
+        cfg = self.cfg
+        itemsize = 1 if cfg.kv_dtype == "int8" else cfg.c_dtype.itemsize
+        grid, live = attention_grid_steps(
+            self.pool.lens + 1, self.ec.page_size,
+            cfg.num_kv_heads // self.tp, cfg.head_dim, itemsize,
+            cfg.num_heads // cfg.num_kv_heads, n_cols)
+        return {"attn_grid_steps": grid, "attn_live_steps": live}
 
     def _spec_step(self) -> List[Request]:
         """One draft→verify→accept iteration over every live slot.

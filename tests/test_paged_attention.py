@@ -9,14 +9,20 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_smoke_config
-from repro.kernels.paged_decode_attention import (paged_decode_attention,
-                                                  paged_kv_bytes)
+from repro.kernels.paged_decode_attention import (
+    attention_blocks, attention_grid_steps, paged_decode_attention,
+    paged_kv_bytes)
 from repro.kernels.ref import paged_decode_attention_ref
 from repro.models.api import model_fns
 from repro.models.layers import decode_attention
 from repro.serving import EngineConfig, InferenceEngine
 from repro.serving.kv_slots import PagedSlotPool
 from tests.test_serving import naive_greedy
+
+
+#: head dims the kernel tests run at: pages of a narrow D are pipelined
+#: blocks, those of a 128-lane D are copied by hand
+HEAD_DIMS = (16, 128)
 
 
 def _paged_case(lens, page_size, hkv=2, g=2, d=16, n_cols=None, seed=0):
@@ -63,27 +69,39 @@ class TestPagedAttentionMath:
         np.testing.assert_allclose(np.asarray(ref), np.asarray(dense),
                                    atol=1e-5, rtol=1e-5)
 
-    @pytest.mark.parametrize("g", [1, 2, 4])     # GQA ratios incl. MHA
+    @pytest.mark.parametrize("g", [1, 2, 4, 8])  # GQA ratios incl. MHA
     def test_gqa_ratios(self, g):
-        q, kp, vp, bt, lv, kd, vd = _paged_case((9, 17), 8, hkv=2, g=g)
-        ref = paged_decode_attention_ref(q, kp, vp, bt, lv)
-        dense = decode_attention(q, kd, vd, lv)
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(dense),
-                                   atol=1e-5, rtol=1e-5)
+        for d in HEAD_DIMS:
+            # the 200-token slot spans two 16-page kernel steps
+            q, kp, vp, bt, lv, kd, vd = _paged_case((9, 17, 200), 8, hkv=2,
+                                                    g=g, d=d)
+            ref = paged_decode_attention_ref(q, kp, vp, bt, lv)
+            dense = decode_attention(q, kd, vd, lv)
+            np.testing.assert_allclose(np.asarray(ref), np.asarray(dense),
+                                       atol=1e-5, rtol=1e-5)
+            got = paged_decode_attention(q, kp, vp, bt, lv, interpret=True)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                       atol=1e-5, rtol=1e-5)
 
     @pytest.mark.parametrize("lens,page_size", [
         ((13, 8, 25, 1), 8),
         ((16, 32), 16),
         ((5, 0, 12), 8),           # dead slot rides along in the grid
+        # 16 pages a step: 38 and 17 live pages end mid-step; a dead slot
+        ((300, 5, 0, 129), 8),
+        ((0, 200, 0, 33), 8),      # leading dead slots, then live ones
+        ((7, 64, 33), 32),         # table of 3 columns, under 4 a step
+        ((100, 150, 0), 32),       # table of 5 columns, 4 a step
     ])
     def test_kernel_matches_ref(self, lens, page_size):
-        q, kp, vp, bt, lv, _, _ = _paged_case(lens, page_size)
-        ref = paged_decode_attention_ref(q, kp, vp, bt, lv)
-        got = paged_decode_attention(q, kp, vp, bt, lv, interpret=True)
-        live = np.asarray(lv) > 0          # dead-slot rows are garbage
-        np.testing.assert_allclose(np.asarray(got)[live],
-                                   np.asarray(ref)[live],
-                                   atol=1e-5, rtol=1e-5)
+        for d in HEAD_DIMS:
+            q, kp, vp, bt, lv, _, _ = _paged_case(lens, page_size, d=d)
+            ref = paged_decode_attention_ref(q, kp, vp, bt, lv)
+            got = paged_decode_attention(q, kp, vp, bt, lv, interpret=True)
+            live = np.asarray(lv) > 0      # dead-slot rows are garbage
+            np.testing.assert_allclose(np.asarray(got)[live],
+                                       np.asarray(ref)[live],
+                                       atol=1e-5, rtol=1e-5)
 
     def test_kernel_gqa_group_padding(self):
         # H=12 over Hkv=4 → G=3, padded to the sublane granule inside
@@ -101,6 +119,36 @@ class TestPagedAttentionMath:
         narrow = paged_decode_attention_ref(q, kp, vp, bt[:, :2], lv)
         np.testing.assert_allclose(np.asarray(wide), np.asarray(narrow),
                                    atol=1e-6)
+
+    @pytest.mark.parametrize("lens,page_size,hkv,rows,n_cols", [
+        ((300, 5, 0, 129), 8, 2, 2, 38),
+        ((100, 150, 0), 32, 2, 2, 5),
+        ((7,), 16, 8, 4, 1),
+        ((2000, 1, 700), 16, 8, 4 * 512, 128),   # long append: head groups
+    ])
+    def test_grid_step_counts(self, lens, page_size, hkv, rows, n_cols):
+        """``attention_grid_steps`` against a walk of the kernel's grid:
+        a step is live when one of its table columns is a live page."""
+        pages, heads = attention_blocks(page_size, hkv, 16, 2, rows, n_cols)
+        grid = live = 0
+        for n in lens:
+            for _ in range(hkv // heads):
+                for j in range(-(-n_cols // pages)):
+                    grid += 1
+                    live += j * pages * page_size < n
+        assert attention_grid_steps(np.asarray(lens), page_size, hkv, 16, 2,
+                                    rows, n_cols) == (grid, live)
+
+    @pytest.mark.parametrize("args,blocks", [
+        ((16, 8, 128, 2, 4, 128), (8, 8)),     # decode: 128 lanes, all heads
+        ((16, 8, 128, 2, 4, 4), (4, 8)),       # capped by the table
+        ((16, 20, 128, 2, 1, 128), (8, 20)),   # MHA
+        ((16, 8, 128, 2, 4 * 64, 128), (8, 4)),    # append: head groups
+        ((16, 8, 128, 2, 4 * 512, 128), (8, 1)),   # long append suffix
+        ((256, 2, 64, 1, 8, 16), (1, 2)),      # pages wider than 128 lanes
+    ])
+    def test_block_rule(self, args, blocks):
+        assert attention_blocks(*args) == blocks
 
     def test_kv_bytes_scale_with_live_tokens(self):
         few = paged_kv_bytes(np.asarray([3, 3]), 8, 2, 16)

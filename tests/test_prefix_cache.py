@@ -76,19 +76,28 @@ class TestPrefillAppendMath:
                                        np.asarray(o[0]),
                                        atol=1e-5, rtol=1e-5)
 
-    @pytest.mark.parametrize("g", [1, 2, 3, 4])    # GQA ratios incl. MHA
-    def test_kernel_matches_ref(self, g):
-        q, kp, vp, bt, pl, tl = _append_case((13, 25, 8), (5, 16, 0), 8,
-                                             g=g)
-        ref = paged_prefill_append_ref(q, kp, vp, bt, pl, tl)
-        got = paged_prefill_append_attention(q, kp, vp, bt, pl, tl,
-                                             interpret=True)
-        # rows past each slot's true suffix are garbage on both sides
-        for i, (t, p) in enumerate(zip((13, 25, 8), (5, 16, 0))):
-            sfx = t - p
-            np.testing.assert_allclose(np.asarray(got[i, :sfx]),
-                                       np.asarray(ref[i, :sfx]),
-                                       atol=1e-5, rtol=1e-5)
+    @pytest.mark.parametrize("g,totals,plens,page_size", [
+        # GQA ratios incl. MHA
+        pytest.param(g, (13, 25, 8), (5, 16, 0), 8, id=str(g))
+        for g in (1, 2, 3, 4)] + [
+        # suffixes whose causal mask crosses a 128-position kernel step
+        pytest.param(2, (150, 140, 40), (120, 131, 0), 8, id="step-8"),
+        pytest.param(4, (300, 70), (250, 60), 32, id="step-32"),
+    ])
+    def test_kernel_matches_ref(self, g, totals, plens, page_size):
+        # head dims of pipelined (16) and hand-copied (128) pages
+        for d in (16, 128):
+            q, kp, vp, bt, pl, tl = _append_case(totals, plens, page_size,
+                                                 g=g, d=d)
+            ref = paged_prefill_append_ref(q, kp, vp, bt, pl, tl)
+            got = paged_prefill_append_attention(q, kp, vp, bt, pl, tl,
+                                                 interpret=True)
+            # rows past each slot's true suffix are garbage on both sides
+            for i, (t, p) in enumerate(zip(totals, plens)):
+                sfx = t - p
+                np.testing.assert_allclose(np.asarray(got[i, :sfx]),
+                                           np.asarray(ref[i, :sfx]),
+                                           atol=1e-5, rtol=1e-5)
 
     def test_decode_is_the_s1_special_case(self):
         """The 1-row flash-decode is the S=1, plen=len-1 instance of the

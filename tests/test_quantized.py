@@ -93,30 +93,44 @@ def _quantized_paged_case(lens, page_size, hkv=2, g=4, d=64, seed=0):
     ([3, 17, 64, 50], 16),    # partial pages + ragged
     ([1, 5], 8),              # single-page shorties
     ([32, 32, 32], 16),       # exact page boundaries
+    ([300, 3, 129, 40], 8),   # 16 pages a kernel step, ends mid-step
+    ([70, 200], 64),          # 2 pages a step, a table of 4 columns
 ])
 def test_quantized_decode_kernel_matches_ref(lens, page_size):
-    q, kc, vc, ks, vs, bt, lv, kd, vd = _quantized_paged_case(lens, page_size)
-    ref = paged_decode_attention_ref(q, kc, vc, bt, lv,
-                                     k_scale=ks, v_scale=vs)
-    # scale-aware ref equals the fp ref on the dequantized cache
-    ref_fp = paged_decode_attention_ref(q, kd, vd, bt, lv)
-    np.testing.assert_allclose(np.asarray(ref), np.asarray(ref_fp),
-                               atol=1e-5, rtol=1e-5)
-    got = paged_decode_attention(q, kc, vc, bt, lv, k_scale=ks, v_scale=vs,
-                                 interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
+    # head dims of pipelined (64) and hand-copied (128) pages
+    for d in (64, 128):
+        q, kc, vc, ks, vs, bt, lv, kd, vd = _quantized_paged_case(
+            lens, page_size, d=d)
+        ref = paged_decode_attention_ref(q, kc, vc, bt, lv,
+                                         k_scale=ks, v_scale=vs)
+        # scale-aware ref equals the fp ref on the dequantized cache
+        ref_fp = paged_decode_attention_ref(q, kd, vd, bt, lv)
+        np.testing.assert_allclose(np.asarray(ref), np.asarray(ref_fp),
+                                   atol=1e-5, rtol=1e-5)
+        got = paged_decode_attention(q, kc, vc, bt, lv, k_scale=ks,
+                                     v_scale=vs, interpret=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
 
 
 def test_quantized_prefill_append_kernel_matches_ref():
-    lens = [3, 17, 64, 50]
-    page_size, s = 16, 8
-    q1, kc, vc, ks, vs, bt, _, kd, vd = _quantized_paged_case(lens, page_size)
+    for case in (([3, 17, 64, 50], [0, 9, 56, 50], [6, 17, 64, 58], 8),
+                 # suffixes whose causal mask crosses the 128-position
+                 # kernel step
+                 ([140, 200], [120, 126], [140, 136], 20)):
+        for d in (64, 128):      # pipelined and hand-copied pages
+            _check_quantized_append(*case, d)
+
+
+def _check_quantized_append(lens, plen, tlen, s, d):
+    page_size = 16
+    q1, kc, vc, ks, vs, bt, _, kd, vd = _quantized_paged_case(lens, page_size,
+                                                              d=d)
     b, _, h, d = q1.shape
     rng = np.random.default_rng(7)
     qs = jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.float32)
-    plen = jnp.asarray([0, 9, 56, 50], jnp.int32)
-    tlen = jnp.asarray([6, 17, 64, 50 + s], jnp.int32)
+    plen = jnp.asarray(plen, jnp.int32)
+    tlen = jnp.asarray(tlen, jnp.int32)
     ref = paged_prefill_append_ref(qs, kc, vc, bt, plen, tlen,
                                    k_scale=ks, v_scale=vs)
     ref_fp = paged_prefill_append_ref(qs, kd, vd, bt, plen, tlen)

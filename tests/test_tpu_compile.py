@@ -117,17 +117,30 @@ def test_bcr_spmm_grouped_compiles(one_chip, epilogue, use_planes, int8):
     assert "bcr_spmm_grouped" in hlo
 
 
-@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("suffix", [1, 32], ids=["decode", "append"])
-def test_paged_attention_compiles(one_chip, suffix, int8):
-    n_pages, n_cols = 1 + BATCH * 32, 32
+#: (batch, heads, kv heads, head dim, table width): llama3.2-1b at batch 8;
+#: pixtral-12b-l20.batch_gen's decode (24 slots, 8 kv heads of 32, 128
+#: columns), its kv heads' share under a mesh of 4, and qwen1.5-4b's MHA
+SHAPES = {"llama": (BATCH, HEADS, KV_HEADS, HEAD_DIM, 32),
+          "cell": (24, 32, 8, 128, 128), "tp4": (24, 8, 2, 128, 128),
+          "mha": (8, 20, 20, 128, 128)}
+
+
+@pytest.mark.parametrize("suffix,int8,shape", [
+    pytest.param(suffix, int8, "llama", id=f"{sid}-{iid}")
+    for int8, iid in ((False, "bf16"), (True, "int8"))
+    for suffix, sid in ((1, "decode"), (32, "append"))] + [
+    pytest.param(1, False, shape, id=f"decode-bf16-{shape}")
+    for shape in ("cell", "tp4", "mha")])
+def test_paged_attention_compiles(one_chip, suffix, int8, shape):
+    batch, heads, kv_heads, head_dim, n_cols = SHAPES[shape]
+    n_pages = 1 + batch * n_cols
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    pool = (n_pages, KV_HEADS, PAGE, HEAD_DIM)
+    pool = (n_pages, kv_heads, PAGE, head_dim)
     kv = S(pool, jnp.int8 if int8 else jnp.bfloat16)
     scales = (S(pool[:-1], jnp.float32),) * 2 if int8 else ()
-    q = S((BATCH, suffix, HEADS, HEAD_DIM), jnp.bfloat16)
-    tables = S((BATCH, n_cols), jnp.int32)
-    lens = S((BATCH,), jnp.int32)
+    q = S((batch, suffix, heads, head_dim), jnp.bfloat16)
+    tables = S((batch, n_cols), jnp.int32)
+    lens = S((batch,), jnp.int32)
 
     def scale_kw(s):
         return dict(k_scale=s[0], v_scale=s[1]) if s else {}

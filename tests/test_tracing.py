@@ -1,7 +1,8 @@
 """Host spans and KV-page gauges of the serving engine and its HTTP front
 end, read back from real ``jax.profiler`` traces (CPU, smoke config, paged
 pool): the span tree and its order, the decode dispatch's ``rows`` and
-``ctx_tokens`` against the pool's live lengths, the three page gauges
+``ctx_tokens`` against the pool's live lengths and its attention-kernel
+step counts against a recount from them, the three page gauges
 summing to the pool's pages through admission, decode, release,
 preemption and a prefix-cache hit, the same gauges on ``/metrics``, and
 greedy tokens unchanged by an active profiler session."""
@@ -17,6 +18,7 @@ import pytest
 from jax.profiler import ProfileData
 
 from repro.configs import get_smoke_config
+from repro.kernels.paged_decode_attention import attention_blocks
 from repro.models.api import model_fns
 from repro.serving import EngineConfig, InferenceEngine, OracleDraft
 from repro.serving.server import (ServerConfig, http_request,
@@ -202,6 +204,26 @@ def test_decode_dispatch_args_are_the_live_lengths(runs, scenario):
         # a re-admitted request can finish on its prefill's token
         assert span.args["rows"] <= live_at[span.args["step"]]
         assert span.args["ctx_tokens"] == int((live + 1).sum())
+        assert (span.args["attn_grid_steps"],
+                span.args["attn_live_steps"]) == attn_steps(run["engine"],
+                                                            lens)
+
+
+def attn_steps(eng, lens):
+    """One layer's paged-attention grid steps and live steps, recounted
+    from the pool's lengths: every slot attends over ``len + 1``
+    positions of a table as wide as the pow2 bucket of the longest live
+    slot's pages, walked ``pages`` columns and ``heads`` KV heads a step."""
+    cfg = eng.cfg
+    cdiv = lambda a, b: -(-a // b)
+    need = cdiv(int(lens.max()) + 1, PAGE)
+    width = min(1 << (need - 1).bit_length(), eng.pool.max_pages)
+    pages, heads = attention_blocks(
+        PAGE, cfg.num_kv_heads, cfg.head_dim, cfg.c_dtype.itemsize,
+        cfg.num_heads // cfg.num_kv_heads, width)
+    groups = cfg.num_kv_heads // heads
+    live = sum(cdiv(cdiv(int(n) + 1, PAGE), pages) for n in lens)
+    return len(lens) * groups * cdiv(width, pages), groups * live
 
 
 @pytest.mark.parametrize("scenario", sorted(SCENARIOS))
