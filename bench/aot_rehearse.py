@@ -64,28 +64,14 @@ def main(argv=None) -> None:
             tree)
 
     # abstract packed params through the harness's own build functions
-    from repro.launch.serve import pack_params
     from reference import seeded
-    sm = build._seed_model(m)
+    head, prefix, stack = build.packers(cfg, m, decode_m=n)
     root = jax.eval_shape(lambda: seeded.root_key(0))
-
-    def head_fn(r):
-        h = seeded.head(r, sm)
-        pk = pack_params(cfg, {"lm_head": {"w": h["lm_head"]}},
-                         decode_m=n)
-        return {"embed": {"table": h["embed"]},
-                "final_norm": {"scale": h["final_norm"]},
-                "lm_head": pk["lm_head"]}
-
-    def stack_fn(r):
-        ids = jnp.arange(m["num_hidden_layers"], dtype=jnp.int32)
-        dense = jax.vmap(lambda i: build._program_layer(
-            seeded.layer(r, i, sm), sm["qkv_bias"]))(ids)
-        return pack_params(cfg, {"stack": [dense]}, decode_m=n)["stack"]
-
-    params = jax.eval_shape(head_fn, root)
-    params["prefix"] = []
-    params["stack"] = jax.eval_shape(stack_fn, root)
+    params = jax.eval_shape(head, root)
+    params["prefix"] = jax.eval_shape(prefix, root)
+    layers = jnp.arange(len(params["prefix"]), m["num_hidden_layers"],
+                        dtype=jnp.int32)
+    params["stack"] = jax.eval_shape(stack, root, layers)
     leaves = jax.tree_util.tree_leaves(params)
     pbytes = sum(x.size * x.dtype.itemsize for x in leaves)
     print(f"{m['name']}: packed params {pbytes} B "

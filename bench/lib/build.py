@@ -4,10 +4,12 @@ program's own ``pack_params``), and the engine.
 
 The program's ``launch/serve.py:build_params`` is not used: it runs
 ``init_params`` for the whole dense fp32 model on the device before
-packing, which does not fit one chip for these models. Here each chunk of
-layers is generated dense (``reference/seeded.py``), packed, plan-tuned
-and fused by ``pack_params`` in one jitted call, and written into a
-preallocated packed stack; the whole dense model never exists at once.
+packing, which does not fit one chip for these models. Here the head, the
+leading unstacked layers and each chunk of stacked layers are generated
+dense by the configuration's family (``families/``), packed, plan-tuned
+and fused by ``pack_params`` in one jitted call each, and the chunks are
+written into a preallocated packed stack; the whole dense model never
+exists at once.
 """
 
 from __future__ import annotations
@@ -19,26 +21,20 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+import families
 from reference import seeded
-
-#: published config keys -> the program's ModelConfig fields
-_KEYS = {"num_hidden_layers": "num_layers", "hidden_size": "d_model",
-         "num_attention_heads": "num_heads",
-         "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
-         "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-         "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
-         "qkv_bias": "qkv_bias"}
 
 
 def program_config(m: Dict[str, Any], impl: Optional[str] = None):
     """The program's ModelConfig for config file ``m``: the repo preset
-    named by ``arch`` with every size from the file, BCR from the file,
-    and the kernel backend the platform selects."""
+    named by ``arch`` with every size from the file (by its family's
+    ``KEYS``), BCR from the file, and the kernel backend the platform
+    selects."""
     from repro.configs import get_config
     from repro.launch.serve import select_backend
     cfg = get_config(m["arch"])
     cfg = dataclasses.replace(
-        cfg, **{f: m[k] for k, f in _KEYS.items()},
+        cfg, **{f: m[k] for k, f in families.of(m).KEYS.items()},
         bcr_keep_frac=float(m["bcr_keep"]),
         bcr_block=(int(m["bcr_block"]),) * 2)
     kernel_impl, attn_impl = select_backend(impl,
@@ -49,31 +45,26 @@ def program_config(m: Dict[str, Any], impl: Optional[str] = None):
     return cfg
 
 
-def _program_layer(w: Dict[str, jax.Array], qkv_bias: bool
-                   ) -> Dict[str, Any]:
-    """Seeded layer weights in the program's param-tree layout."""
-    def lin(name, bias=None):
-        out = {"w": w[name]}
-        if bias is not None:
-            out["b"] = w[bias]
-        return out
-    b = qkv_bias
-    return {
-        "norm1": {"scale": w["n1"]},
-        "mixer": {"wq": lin("wq", "bq" if b else None),
-                  "wk": lin("wk", "bk" if b else None),
-                  "wv": lin("wv", "bv" if b else None),
-                  "wo": lin("wo")},
-        "norm2": {"scale": w["n2"]},
-        "ffn": {"wg": lin("wg"), "wi": lin("wi"), "wo": lin("wd")},
-    }
+def packers(cfg, m: Dict[str, Any], decode_m: int, weight_dtype: str = ""):
+    """(head, prefix, stack): functions of the seed's root key (and, for
+    the stack, of the absolute indices of its layers) that make the
+    family's dense weights and pack them with the program's packer."""
+    from repro.launch.serve import pack_params
+    fam = families.of(m)
 
+    def pack(tree):
+        return pack_params(cfg, tree, decode_m=decode_m,
+                           weight_dtype=weight_dtype)
 
-def _seed_model(m: Dict[str, Any]) -> Dict[str, Any]:
-    return {k: m[k] for k in ("hidden_size", "intermediate_size",
-                              "num_attention_heads", "num_key_value_heads",
-                              "head_dim", "qkv_bias", "vocab_size",
-                              "bcr_block", "bcr_keep")}
+    def head(root):
+        return pack(fam.head(root, m))
+
+    def prefix(root):
+        return pack({"prefix": fam.prefix(root, m)})["prefix"]
+
+    def stack(root, layers):
+        return pack({"stack": fam.stack(root, layers, m)})["stack"]
+    return head, prefix, stack
 
 
 def build_params(cfg, m: Dict[str, Any], seed: int, decode_m: int,
@@ -82,39 +73,25 @@ def build_params(cfg, m: Dict[str, Any], seed: int, decode_m: int,
     ``weight_dtype="int8"`` runs the packer's own int8 quantization on each
     chunk (the precision control), so the fp32 tiles of the whole model and
     their int8 codes never coexist on the device."""
-    from repro.launch.serve import pack_params
-    sm = _seed_model(m)
-    n_layers = int(m["num_hidden_layers"])
-    chunk = int(m["build_chunk_layers"])
-    if n_layers % chunk:
-        raise ValueError(f"build_chunk_layers {chunk} must divide "
-                         f"{n_layers} layers")
-
-    @jax.jit
-    def head_fn(root):
-        h = seeded.head(root, sm)
-        packed = pack_params(cfg, {"lm_head": {"w": h["lm_head"]}},
-                             decode_m=decode_m, weight_dtype=weight_dtype)
-        return {"embed": {"table": h["embed"]},
-                "final_norm": {"scale": h["final_norm"]},
-                "lm_head": packed["lm_head"]}
-
-    @jax.jit
-    def chunk_fn(root, ids):
-        dense = jax.vmap(lambda i: _program_layer(
-            seeded.layer(root, i, sm), sm["qkv_bias"]))(ids)
-        return pack_params(cfg, {"stack": [dense]}, decode_m=decode_m,
-                           weight_dtype=weight_dtype)["stack"]
-
+    head, prefix, stack = (jax.jit(f) for f in
+                           packers(cfg, m, decode_m, weight_dtype))
     root = seeded.root_key(seed)
-    ids0 = jnp.arange(chunk, dtype=jnp.int32)
-    shapes = jax.eval_shape(chunk_fn, root, ids0)
-    leaves, treedef = jax.tree_util.tree_flatten(shapes)
+    params = head(root)             # largest transient first, while free
+    params["prefix"] = prefix(root)
+    first = len(params["prefix"])
+    rows = int(m["num_hidden_layers"]) - first
+    chunk = int(m["build_chunk_layers"])
+    if rows % chunk:
+        raise ValueError(f"build_chunk_layers {chunk} must divide the "
+                         f"{rows} stacked layers")
+    ids0 = jnp.arange(first, first + chunk, dtype=jnp.int32)
+    leaves, treedef = jax.tree_util.tree_flatten(
+        jax.eval_shape(stack, root, ids0))
 
     @jax.jit
     def zeros_fn():
         return jax.tree_util.tree_unflatten(treedef, [
-            jnp.zeros((n_layers,) + s.shape[1:], s.dtype) for s in leaves])
+            jnp.zeros((rows,) + s.shape[1:], s.dtype) for s in leaves])
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def put_fn(full, part, start):
@@ -122,14 +99,12 @@ def build_params(cfg, m: Dict[str, Any], seed: int, decode_m: int,
             lambda f, p: jax.lax.dynamic_update_slice_in_dim(f, p, start, 0),
             full, part)
 
-    params = head_fn(root)          # largest transient first, while free
-    stack = zeros_fn()
-    for start in range(0, n_layers, chunk):
-        part = chunk_fn(root, ids0 + start)
-        stack = put_fn(stack, part, jnp.int32(start))
+    full = zeros_fn()
+    for start in range(0, rows, chunk):
+        part = stack(root, ids0 + start)
+        full = put_fn(full, part, jnp.int32(start))
         del part
-    params["prefix"] = []
-    params["stack"] = stack
+    params["stack"] = full
     jax.block_until_ready(params)
     return params
 

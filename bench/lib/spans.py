@@ -9,12 +9,14 @@ clock (``src/repro/serving/tracing.py``): ``engine.step`` (args ``step``,
 ``engine.spec``; beside the steps, ``server.pump`` and ``server.idle``.
 They land in the same ``.xplane.pb`` as the device's programs.
 
-``for_run(ctx)`` loads them, with the harness's ``bench:window`` and
-``bench:decode:<k>`` spans, from the newest trace under ``bench/.traces/``
-(the run's context holds no path) and refuses a trace that is not the
-run's own: every decode call it names must be in the run's decode log,
-and its window must be the one the reducer measured. A program that
-records no such span (one older than them) gives None, and so do the
+``for_run(ctx)`` loads them, with the harness's ``bench:window`` span, from
+the newest trace under ``bench/.traces/`` (the run's context holds no path)
+and refuses a trace that is not the run's own: its window must be the one
+the reducer measured, and its ``engine.decode.dispatch`` spans must name
+each step once and be no more than the decode steps that the run's engine
+counted from just before the profiler's start to just after its stop
+(``ctx["traced_decodes"]``), and the one step then in flight. A program
+that records no such span (one older than them) gives None, and so do the
 readers; a trace that holds some of them but no ``engine.step`` is a
 fault. The metrics ``host_gap_ms``, ``pump_ms`` and ``kv_reserved_share``
 read them.
@@ -24,24 +26,20 @@ from __future__ import annotations
 
 import bisect
 import glob
-import gzip
 import os
 import sys
-from collections import defaultdict, namedtuple
+from collections import defaultdict
 from typing import Any, Dict, List, Optional
 
-from lib.trace import DECODE_CALL, WINDOW
+from lib.trace import DISPATCH, WINDOW, Span, host_spans, read_xspace
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TRACES = os.path.join(BENCH, ".traces")
-PROGRAM = ("engine.", "server.")
 STEP = "engine.step"
 CHILDREN = ("engine.admit", "engine.prefill", "engine.decode.dispatch",
             "engine.decode.wait", "engine.commit", "engine.spec")
-DISPATCH, WAIT = "engine.decode.dispatch", "engine.decode.wait"
+WAIT = "engine.decode.wait"
 GAUGES = ("kv_pages_in_use", "kv_pages_reserved", "kv_pages_free")
-
-Span = namedtuple("Span", "name a b args")
 #: where ``for_run`` keeps the loaded trace in the run's context, so the
 #: readers of one run parse it once
 CTX_KEY = "program_spans"
@@ -55,49 +53,30 @@ def newest() -> Optional[str]:
 
 
 def load(path: str) -> Dict[str, Any]:
-    """The program's spans (times in seconds, args as recorded), the
-    ``bench:window`` span and the decode-call indices of a trace file
-    (``.xplane.pb``, or the same gzipped)."""
-    from jax.profiler import ProfileData
-    if path.endswith(".gz"):
-        with gzip.open(path, "rb") as f:
-            pd = ProfileData.from_serialized_xspace(f.read())
-    else:
-        pd = ProfileData.from_file(path)
-    spans: List[Span] = []
-    windows, calls = [], []
-    for plane in pd.planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            for ev in line.events:
-                name = ev.name
-                a = ev.start_ns * 1e-9
-                b = (ev.start_ns + ev.duration_ns) * 1e-9
-                if name.startswith(PROGRAM):
-                    spans.append(Span(name, a, b, dict(ev.stats)))
-                elif name == WINDOW:
-                    windows.append((a, b))
-                elif name.startswith(DECODE_CALL):
-                    calls.append(int(name[len(DECODE_CALL):]))
+    """The program's spans (times in seconds, args as recorded) and the
+    ``bench:window`` span of a trace file (``.xplane.pb``, or the same
+    gzipped)."""
+    host = host_spans(read_xspace(path))
+    windows = [(s.a, s.b) for s in host if s.name == WINDOW]
     if len(windows) != 1:
         raise ValueError(f"trace holds {len(windows)} {WINDOW!r} spans, "
                          f"not one")
-    spans.sort(key=lambda s: (s.a, -s.b))
-    return {"path": path, "window": windows[0], "spans": spans,
-            "calls": sorted(calls)}
+    return {"path": path, "window": windows[0],
+            "spans": [s for s in host if s.name != WINDOW]}
 
 
 def check(run: Dict[str, Any], ctx: Dict[str, Any]) -> None:
     """Refuse a trace that is not the run's own."""
-    stray = sorted(set(run["calls"]) - set(ctx["decode_log"]))
-    if stray:
-        raise ValueError(f"{run['path']}: decode calls {stray[:5]} are not "
-                         f"in this run's decode log")
     a, b = run["window"]
     if abs((b - a) - ctx["trace"]["window_s"]) > 1e-6:
         raise ValueError(f"{run['path']}: window of {b - a!r} s, but the "
                          f"run traced {ctx['trace']['window_s']!r} s")
+    steps = [s.args["step"] for s in run["spans"] if s.name == DISPATCH]
+    counted = ctx["traced_decodes"]
+    if len(set(steps)) != len(steps) or len(steps) > counted + 1:
+        raise ValueError(f"{run['path']}: {len(steps)} decode steps "
+                         f"{sorted(steps)[:5]}..., but the run's engine "
+                         f"counted {counted} while the profiler ran")
 
 
 def for_run(ctx: Dict[str, Any]) -> Optional[Dict[str, Any]]:

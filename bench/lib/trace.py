@@ -8,9 +8,12 @@ Reads the file with ``jax.profiler.ProfileData`` only. Device planes are
 program execution (``jit_decode_sample(...)``) and the ``XLA Ops`` line
 one event per operation, Pallas kernels under their own names
 (``bcr_spmm``, ``bcr_spmm_grouped``, ``paged_attention``). The harness
-marks the traced window with a host span ``bench:window`` and each decode
-call with ``bench:decode:<k>`` (``k`` indexes its log of the call's live
-contexts), so the reducer needs no assumption about the trace's time base.
+marks the traced window with a host span ``bench:window``; the program's
+own host spans (``engine.*``, ``server.*``, ``src/repro/serving/
+tracing.py``) lie on the same clock. Each decode execution is paired with
+the ``engine.decode.dispatch`` span that launched it, whose args (``rows``,
+``ctx_tokens``, ...) are what the required work is counted from, so the
+reducer needs no assumption about the trace's time base.
 """
 
 from __future__ import annotations
@@ -20,17 +23,19 @@ import glob
 import gzip
 import os
 import re
-from collections import defaultdict
+from collections import defaultdict, namedtuple
 from typing import Any, Dict, List, Optional, Tuple
 
 PROGRAMS = ("prefill_sample", "decode_sample", "append_sample",
             "verify_logits")
 KERNELS = ("bcr_spmm_grouped", "bcr_spmm", "paged_attention")
-HOST_PREFIX = "bench:"
-WINDOW = HOST_PREFIX + "window"
-DECODE_CALL = HOST_PREFIX + "decode:"
+WINDOW = "bench:window"
+#: name prefixes of the program's host spans
+PROGRAM = ("engine.", "server.")
+DISPATCH = "engine.decode.dispatch"
 
 Event = Tuple[str, float, float]
+Span = namedtuple("Span", "name a b args")
 
 
 def find_xplane(log_dir: str) -> Optional[str]:
@@ -70,28 +75,45 @@ def _events(line) -> List[Event]:
              * 1e-9) for ev in line.events]
 
 
-def load(path: str) -> Dict[str, Any]:
-    """Device and harness host events of a trace (``.xplane.pb``, or the
-    same gzipped), in seconds on one clock."""
+def read_xspace(path: str):
+    """A trace file (``.xplane.pb``, or the same gzipped) as JAX's
+    ``ProfileData``."""
     from jax.profiler import ProfileData
     if path.endswith(".gz"):
         with gzip.open(path, "rb") as f:
-            pd = ProfileData.from_serialized_xspace(f.read())
-    else:
-        pd = ProfileData.from_file(path)
+            return ProfileData.from_serialized_xspace(f.read())
+    return ProfileData.from_file(path)
+
+
+def host_spans(pd) -> List[Span]:
+    """The harness's ``bench:window`` and the program's host spans, with
+    their args as recorded, in seconds, by start (outer first)."""
+    out = []
+    for plane in pd.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for ln in plane.lines:
+            for ev in ln.events:
+                if ev.name == WINDOW or ev.name.startswith(PROGRAM):
+                    a = ev.start_ns * 1e-9
+                    out.append(Span(ev.name, a, a + ev.duration_ns * 1e-9,
+                                    dict(ev.stats)))
+    out.sort(key=lambda s: (s.a, -s.b))
+    return out
+
+
+def load(path: str) -> Dict[str, Any]:
+    """Device events and host spans (``host_spans``) of a trace file, in
+    seconds on one clock."""
+    pd = read_xspace(path)
     dev: Dict[str, Dict[str, List[Event]]] = {}
-    host: List[Event] = []
     for plane in pd.planes:
         if re.fullmatch(r"/device:TPU:\d+", plane.name):
             lines = {ln.name: _events(ln) for ln in plane.lines}
             dev[plane.name] = {
                 "modules": lines.get("XLA Modules", []),
                 "ops": lines.get("XLA Ops", [])}
-        elif plane.name.startswith("/host:"):
-            for ln in plane.lines:
-                host.extend(e for e in _events(ln)
-                            if e[0].startswith(HOST_PREFIX))
-    return {"devices": dev, "host": host}
+    return {"devices": dev, "host": host_spans(pd)}
 
 
 def union(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
@@ -104,9 +126,11 @@ def union(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
     return [(a, b) for a, b in out]
 
 
-def _covering(events: List[Event], t: float) -> Optional[str]:
+def _covering(events, t: float) -> Optional[str]:
+    """The name of the shortest of ``events`` (name, start, end, ...) that
+    covers ``t``."""
     best = None
-    for name, a, b in events:
+    for name, a, b, *_ in events:
         if a <= t <= b and (best is None or b - a < best[2] - best[1]):
             best = (name, a, b)
     return best[0] if best else None
@@ -114,23 +138,23 @@ def _covering(events: List[Event], t: float) -> Optional[str]:
 
 def window_of(raw: Dict[str, Any]) -> Tuple[float, float]:
     """The traced window: the harness's ``bench:window`` host span."""
-    spans = [(a, b) for n, a, b in raw["host"] if n == WINDOW]
+    spans = [(s.a, s.b) for s in raw["host"] if s.name == WINDOW]
     if len(spans) != 1:
         raise ValueError(f"trace holds {len(spans)} {WINDOW!r} host spans, "
                          f"not one")
     return spans[0]
 
 
-def _executions(mods: List[Event], ops: List[Event], calls: List[Event],
+def _executions(mods: List[Event], ops: List[Event], dispatch: List[Span],
                 t0: float, t1: float) -> Dict[str, List[Dict[str, Any]]]:
     """Program executions lying wholly inside ``[t0, t1]``: each one's
-    device seconds, kernel seconds inside it, and (decode) the index of
-    the harness's decode call that launched it: the latest one that
-    started before it did."""
+    device seconds, kernel seconds inside it, and (decode) the args of the
+    ``engine.decode.dispatch`` span that launched it: the latest one that
+    started before it did (None where none did)."""
     ops = sorted((a, b, n) for n, a, b in ops if kernel_of(n))
     starts = [a for a, _, _ in ops]
-    calls = sorted((a, int(n[len(DECODE_CALL):])) for n, a, _ in calls)
-    call_starts = [a for a, _ in calls]
+    dispatch = sorted(dispatch, key=lambda s: s.a)
+    dispatch_starts = [s.a for s in dispatch]
     out: Dict[str, List[Dict[str, Any]]] = defaultdict(list)
     for n, a, b in sorted(mods, key=lambda e: e[1]):
         p = program_of(n)
@@ -142,11 +166,11 @@ def _executions(mods: List[Event], ops: List[Event], calls: List[Event],
                 break
             if ob <= b:
                 kern[kernel_of(on)] += ob - oa
-        ex = {"s": b - a, "kernels": dict(kern), "call": None}
+        ex = {"s": b - a, "kernels": dict(kern), "dispatch": None}
         if p == "decode_sample":
-            j = bisect.bisect_right(call_starts, a) - 1
+            j = bisect.bisect_right(dispatch_starts, a) - 1
             if j >= 0:
-                ex["call"] = calls[j][1]
+                ex["dispatch"] = dispatch[j].args
         out[p].append(ex)
     return dict(out)
 
@@ -165,7 +189,7 @@ def reduce(raw: Dict[str, Any]) -> Dict[str, Any]:
     if not any(a < t_stop and b > t_start for _, a, b in evs):
         raise ValueError(f"no device operation overlaps the traced window "
                          f"[{t_start!r}, {t_stop!r}] s")
-    calls = [e for e in raw["host"] if e[0].startswith(DECODE_CALL)]
+    dispatch = [s for s in raw["host"] if s.name == DISPATCH]
 
     def clip(events):
         return [(n, max(a, t_start), min(b, t_stop)) for n, a, b in events
@@ -180,25 +204,27 @@ def reduce(raw: Dict[str, Any]) -> Dict[str, Any]:
             fam[op_family(n)] += b - a
         per_dev.append({"busy_s": busy, "families": dict(fam), "ops": ops,
                         "modules": clip(d["modules"]),
-                        "execs": _executions(d["modules"], d["ops"], calls,
-                                             t_start, t_stop)})
+                        "execs": _executions(d["modules"], d["ops"],
+                                             dispatch, t_start, t_stop)})
     d0 = per_dev[0]
-    # idle gaps on the first device, labelled by the programs around them
-    # and by the harness's innermost host span that covers the gap's middle
+    # the longest idle gaps on the first device, labelled by the programs
+    # around them and by the program's innermost host span that covers the
+    # gap's middle
     u = union([(a, b) for _, a, b in d0["ops"]])
+    longest = sorted(((a1 - b0, b0, a1) for (_, b0), (a1, _) in
+                      zip(u, u[1:])), key=lambda g: -g[0])[:10]
+    mods = d0["modules"]
+    inner = [s for s in raw["host"] if s.name != WINDOW]
     gaps = []
-    mods = sorted(d0["modules"], key=lambda e: e[1])
-    inner = [e for e in raw["host"] if e[0] != WINDOW]
-    for (a0, b0), (a1, b1) in zip(u, u[1:]):
+    for gap, b0, a1 in longest:
         before = _covering(mods, b0 - 1e-9)
         after = _covering(mods, a1 + 1e-9)
         label = (f"{program_of(before or '') or 'none'} -> "
                  f"{program_of(after or '') or 'none'}")
         hspan = _covering(inner, (b0 + a1) / 2)
         if hspan:
-            label += f" [{hspan[len(HOST_PREFIX):].split(':')[0]}]"
-        gaps.append((label, a1 - b0))
-    gaps.sort(key=lambda g: -g[1])
+            label += f" [{hspan}]"
+        gaps.append((label, gap))
     top_ops = sorted(d0["families"].items(), key=lambda kv: -kv[1])[:10]
     execs = d0["execs"]
     return {
@@ -208,7 +234,7 @@ def reduce(raw: Dict[str, Any]) -> Dict[str, Any]:
         "execs": execs,
         "programs": {p: [e["s"] for e in v] for p, v in execs.items()},
         "device_ops": [[n, s] for n, s in top_ops],
-        "idle_gaps": [[n, s] for n, s in gaps[:10]],
+        "idle_gaps": [[n, s] for n, s in gaps],
     }
 
 
@@ -223,6 +249,6 @@ def check_decode(red: Dict[str, Any]) -> None:
     if not any(e["kernels"] for e in ex):
         raise RuntimeError(f"no {KERNELS} event inside any decode_sample "
                            f"execution of the traced window")
-    if all(e["call"] is None for e in ex):
+    if all(e["dispatch"] is None for e in ex):
         raise RuntimeError("no decode execution could be matched to the "
-                           f"harness's {DECODE_CALL!r} host spans")
+                           f"program's {DISPATCH!r} host spans")

@@ -8,14 +8,17 @@ mix (``bench/traffic/<mix>.json``). One run:
 
 1. finds the chips the cell asks for, or exits non-zero with no result;
 2. makes the configuration's BCR-pruned weights on the device from the
-   seed and packs them with the program's own packer, in layer chunks;
+   seed, in the layer tree of the family that its ``reference`` key names
+   (``families/``), and packs them with the program's own packer, in
+   layer chunks;
 3. builds the program's ``InferenceEngine`` with the config's engine
    settings, warms up exactly the programs the mix can reach, and serves
    it over the program's HTTP front end (``serving/server.py``);
 4. starts the stdlib-only load client (``lib/client.py``) as a child
    process, which sends the mix for its warm-in plus ``--seconds``;
 5. with ``--trace 1``, profiles a few seconds in the middle of the window
-   and reduces the trace (``lib/trace.py``);
+   and reduces the trace (``lib/trace.py``), pairing each decode with the
+   program's own ``engine.decode.dispatch`` span;
 6. drains the server, frees the program's state, and compares a sample of
    the served answers with the plain reference (``reference/``);
 7. prints the metrics that ``BENCHMARK.json`` lists for the cell (read by
@@ -265,28 +268,6 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
             on_terminal(req)
     engine.sched.on_terminal = record_terminal
 
-    # traced runs: every decode call's live contexts, by call index; the
-    # call runs under a host span that names the index, so the reducer
-    # can pair each device execution with the call that launched it
-    decode_log: Dict[int, Any] = {}
-    if trace:
-        import numpy as np
-        decode = engine._decode
-
-        def logged_decode(*a, **kw):
-            lens = np.array(engine.pool.lens)
-            k = len(decode_log)
-            decode_log[k] = lens[lens > 0] + 1
-            with jax.profiler.TraceAnnotation(f"bench:decode:{k}"):
-                return decode(*a, **kw)
-        engine._decode = logged_decode
-        step = engine.step
-
-        def annotated_step():
-            with jax.profiler.TraceAnnotation("bench:engine.step"):
-                return step()
-        engine.step = annotated_step
-
     handle = start_in_thread(engine, ServerConfig(port=0))
     child = subprocess.Popen(
         [sys.executable, os.path.join(BENCH, "lib", "client.py")],
@@ -316,7 +297,7 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
                         "compiles": counter.snapshot()}
         time.sleep(max(0.0, t_go + warm_in - time.perf_counter()))
         s0 = snap()
-        traced = False
+        traced_decodes = None
         if trace:
             tlen = min(TRACE_SECONDS, seconds / 2)
             time.sleep(max(0.0, (seconds - tlen) / 2))
@@ -325,12 +306,13 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
             opts = jax.profiler.ProfileOptions()
             opts.host_tracer_level = 1
             opts.python_tracer_level = 0
+            d0 = engine.stats["decode_steps"]
             jax.profiler.start_trace(os.path.join(TRACE_DIR, cell_name),
                                      profiler_options=opts)
             with jax.profiler.TraceAnnotation(trace_lib.WINDOW):
                 time.sleep(tlen)
             jax.profiler.stop_trace()
-            traced = True
+            traced_decodes = engine.stats["decode_steps"] - d0
         time.sleep(max(0.0, t_go + warm_in + seconds - time.perf_counter()))
         s1 = snap()
         out, _ = child.communicate(timeout=180)
@@ -363,24 +345,24 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
     ctx = {"model": m, "peaks": peaks, "setup_s": setup_s,
            "client": client, "t0": t0, "t1": t1, "window": window,
            "occupancy": occ, "n_slots": m["engine"]["n_slots"],
-           "engine_records": records, "trace": None, "decode_log": {}}
+           "engine_records": records, "trace": None,
+           "traced_decodes": traced_decodes}
     breakdown = None
     device = dict(dev, memory_peak_bytes=mem_peak)
-    if traced:
+    if trace:
         path = trace_lib.find_xplane(os.path.join(TRACE_DIR, cell_name))
         red = trace_lib.reduce(trace_lib.load(path))
-        with open(os.path.join(TRACE_DIR, cell_name, "decode_log.json"),
-                  "w") as f:
-            json.dump({k: v.tolist() for k, v in decode_log.items()}, f)
         trace_lib.check_decode(red)
         ctx["trace"] = red
-        ctx["decode_log"] = decode_log
         device.update(busy_s=red["busy_s"], window_s=red["window_s"])
         breakdown = {"device_ops": red["device_ops"],
                      "idle_gaps": red["idle_gaps"]}
+        paired = sum(e["dispatch"] is not None
+                     for e in red["execs"]["decode_sample"])
         log(f"trace: {path}; whole executions "
             f"{ {k: len(v) for k, v in red['programs'].items()} }; "
-            f"decode calls logged {len(decode_log)}")
+            f"decode executions paired with a dispatch span {paired}; "
+            f"decode steps counted while tracing {traced_decodes}")
 
     # free the program's state before the reference runs
     for leaf in jax.tree_util.tree_leaves((engine.params, engine.pool.cache)):

@@ -22,14 +22,14 @@ def red():
 
 
 def test_chip_trace_names_are_found(red):
-    trace.check_decode(red)
     ex = red["execs"]["decode_sample"]
     assert len(ex) == 3
     for e in ex:
         assert set(e["kernels"]) == set(trace.KERNELS)
-        assert e["call"] is not None
+        # the program recorded no spans yet, so nothing pairs a decode
+        # (``test_bench_spans.py`` pairs them on a later trace)
+        assert e["dispatch"] is None
         assert 0.0 < sum(e["kernels"].values()) < e["s"]
-    assert [e["call"] for e in ex] == sorted({e["call"] for e in ex})
 
 
 def test_chip_trace_numbers(red):

@@ -5,6 +5,8 @@ from another run, a program that records no span) and on a trimmed chip
 trace."""
 
 import gzip
+import importlib
+import json
 import os
 
 import pytest
@@ -33,7 +35,7 @@ def step(n, a, b, dispatch_end=None, wait_end=None, gauges=(0, 0, 0)):
     return out
 
 
-def hand_run(window=(0.0, 1.0), calls=(1, 2, 3, 5)):
+def hand_run(window=(0.0, 1.0)):
     ss = (step(1, -0.05, 0.15, 0.0, 0.14, (9, 9, 2))       # cut by start
           + step(2, 0.2, 0.45, 0.22, 0.40, (10, 5, 5))
           + step(3, 0.5, 0.75, 0.52, 0.70, (12, 3, 5))
@@ -45,15 +47,15 @@ def hand_run(window=(0.0, 1.0), calls=(1, 2, 3, 5)):
              Span("server.pump", 0.99, 1.01, {"streams": 3, "tokens": 3}),
              Span("server.idle", 0.46, 0.5, {})])
     return {"path": "hand-made", "window": window,
-            "spans": sorted(ss, key=lambda s: (s.a, -s.b)),
-            "calls": list(calls)}
+            "spans": sorted(ss, key=lambda s: (s.a, -s.b))}
 
 
-def ctx_for(run, decode_log=None):
+def ctx_for(run, traced_decodes=4):
+    """The run's context: its traced window's length, and the decode steps
+    its engine counted while the profiler ran (as many as the four
+    dispatch spans)."""
     t0, t1 = run["window"]
-    log = {k: [10] for k in run["calls"]} if decode_log is None \
-        else decode_log
-    return {"trace": {"window_s": t1 - t0}, "decode_log": log}
+    return {"trace": {"window_s": t1 - t0}, "traced_decodes": traced_decodes}
 
 
 @pytest.fixture
@@ -93,12 +95,28 @@ def test_cut_steps_move_nothing(serve, window):
     assert got == [pytest.approx(v) for v in want]
 
 
-def test_trace_from_another_run_is_refused(serve):
-    run = serve(hand_run())
-    ctx = ctx_for(run, decode_log={k: [10] for k in (1, 2, 3)})
+def doubled(run):
+    """The same trace with one dispatch span recorded twice."""
+    d = next(x for x in run["spans"] if x.name == spans.DISPATCH)
+    return dict(run, spans=sorted(run["spans"] + [d],
+                                  key=lambda x: (x.a, -x.b)))
+
+
+@pytest.mark.parametrize("counted, edit", [(2, None), (9, doubled)])
+def test_trace_from_another_run_is_refused(serve, counted, edit):
+    """More dispatch spans than the engine counted and the one in flight,
+    or one step twice."""
+    run = hand_run()
+    run = serve(edit(run) if edit else run)
     for r in READERS:
-        with pytest.raises(ValueError, match="decode log"):
-            r.read(ctx)
+        with pytest.raises(ValueError, match="decode steps"):
+            r.read(ctx_for(run, counted))
+
+
+def test_run_is_recognised_by_its_window_and_steps(serve):
+    run = serve(hand_run())
+    for counted in (3, 4, 9):
+        assert host_gap_ms.read(ctx_for(run, counted)) is not None
     ctx = ctx_for(run)
     ctx["trace"]["window_s"] += 2e-6
     with pytest.raises(ValueError, match="window"):
@@ -110,7 +128,7 @@ def test_trace_from_another_run_is_refused(serve):
 def test_program_without_spans_reads_nothing(serve):
     run = serve(dict(hand_run(), spans=[]))
     assert read_all(ctx_for(run)) == [None, None, None]
-    assert read_all({"trace": None, "decode_log": {}}) == [None] * 3
+    assert read_all({"trace": None, "traced_decodes": None}) == [None] * 3
 
 
 def test_spans_without_engine_step_fail(serve):
@@ -138,12 +156,18 @@ def test_steps_group_children_by_containment():
 
 # A trace recorded on a TPU v5e by ``bench/run.py --trace 1`` in the cell
 # ``pixtral-12b-l20.batch_gen`` (seed 3130000001), trimmed to the device
-# plane's XLA Modules and XLA Ops lines (no op stats), the harness's
-# ``bench:`` spans and the program's spans with their args, over the first
-# 0.75 s of its window (``bench:window`` shortened to that stretch): steps
-# 93 and 94 whole, 95 cut by the window's end.
+# plane's XLA Modules and XLA Ops lines (no op stats), the harness's host
+# spans and the program's spans with their args, over the first 0.75 s of
+# its window (``bench:window`` shortened to that stretch): steps 93 and 94
+# whole, 95 cut by the window's end. The harness that recorded it also
+# marked each decode call with a host span of its own, inside the
+# dispatch span, and logged the call's live contexts; the readers no
+# longer read those.
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
                     "batch_gen_spans.xplane.pb.gz")
+CONFIGS = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "configs")
+PEAKS = os.path.join(os.path.dirname(CONFIGS), "peaks.json")
 
 
 @pytest.fixture(scope="module")
@@ -154,14 +178,21 @@ def chip():
 
 
 def chip_ctx(chip):
+    """The context of the run that recorded the trimmed trace: three
+    decode steps counted while its profiler ran (93-95)."""
     run, red = chip
-    return {"trace": red, "decode_log": {k: [1] for k in run["calls"]}}
+    with open(os.path.join(CONFIGS, "pixtral-12b-l20.json")) as f:
+        m = json.load(f)
+    with open(PEAKS) as f:
+        peaks = json.load(f)["devices"]["TPU v5 lite"]
+    return {"trace": red, "traced_decodes": 3, "model": m, "peaks": peaks}
 
 
 def test_chip_trace_spans(chip):
     run, red = chip
     trace.check_decode(red)
-    assert run["calls"] == [81, 82, 83]
+    assert [s.args["step"] for s in run["spans"]
+            if s.name == spans.DISPATCH] == [93, 94, 95]
     st = spans.steps(run)
     assert [s["step"] for s in st] == [93, 94]
     for s in st:
@@ -176,6 +207,58 @@ def test_chip_trace_spans(chip):
     assert ctx[1] - ctx[0] == 21
 
 
+def test_chip_trace_pairs_each_decode_with_its_dispatch(chip):
+    """Each whole decode execution (steps 93 and 94) pairs with the
+    dispatch span of its own step: the device's execution starts ~4 ms
+    after its dispatch span, well clear of the ~1 ms by which host and
+    device clocks agree."""
+    _, red = chip
+    ex = red["execs"]["decode_sample"]
+    assert [(e["dispatch"]["step"], e["dispatch"]["rows"],
+             e["dispatch"]["ctx_tokens"]) for e in ex] == [
+        (93, 21, 10986), (94, 21, 11007)]
+
+
+# The parent harness's readings of this trace, with each logged call's
+# contexts rebuilt (count and sum, all its work read) from the dispatch
+# span that holds the call's span. That harness paired an execution with
+# the latest call span that started before it: it left step 93's
+# execution unpaired, and paired step 94's with step 93's call, whose 21
+# contexts hold one token fewer each (10,986 tokens against 11,007).
+PARENT = {"bcr_spmm_roofline": 3.5818567253413787,
+          "paged_attention_roofline": 1.4669645706047694,
+          "decode_mfu": 2.0222924597596443,
+          "decode_step_ms": 244.40563200000003}
+
+
+@pytest.mark.parametrize("name, mend", [
+    ("bcr_spmm_roofline", 1.0),
+    # the attention bytes grow with the context: the parent's reading,
+    # mended for the tokens its pairing missed
+    ("paged_attention_roofline", 11007 / 10986),
+    ("decode_mfu", 1.0),
+    ("decode_step_ms", 1.0)])
+def test_chip_trace_readings_match_the_parent_harness(chip, name, mend):
+    reader = importlib.import_module("metrics." + name)
+    assert reader.read(chip_ctx(chip)) == pytest.approx(
+        PARENT[name] * mend, rel=1e-3)
+
+
+def test_chip_trace_idle_gaps_are_labelled_by_program_spans(chip):
+    """The longest idle gaps of the trimmed window follow a decode while
+    the host still waits on its tokens, then come inside the dispatch
+    around the PRNG split's two programs."""
+    _, red = chip
+    assert [n for n, _ in red["idle_gaps"]] == (
+        ["decode_sample -> none [engine.decode.wait]"] * 2
+        + ["none -> decode_sample [engine.decode.dispatch]"] * 3
+        + ["none -> none [engine.decode.dispatch]"] * 3
+        + ["decode_sample -> decode_sample [engine.decode.wait]",
+           "none -> none [engine.decode.dispatch]"])
+    assert [s for _, s in red["idle_gaps"][:2]] == pytest.approx(
+        [0.0054427, 0.0043722], abs=1e-6)
+
+
 def test_chip_trace_readers(chip, monkeypatch):
     monkeypatch.setattr(spans, "newest", lambda: DATA)
     ctx = chip_ctx(chip)
@@ -187,9 +270,8 @@ def test_chip_trace_readers(chip, monkeypatch):
 
 def test_chip_trace_from_another_run_is_refused(chip, monkeypatch):
     monkeypatch.setattr(spans, "newest", lambda: DATA)
-    ctx = chip_ctx(chip)
-    del ctx["decode_log"][83]
-    with pytest.raises(ValueError, match="decode log"):
+    ctx = dict(chip_ctx(chip), traced_decodes=1)
+    with pytest.raises(ValueError, match="decode steps"):
         pump_ms.read(ctx)
     ctx = chip_ctx(chip)
     ctx["trace"] = dict(ctx["trace"], window_s=ctx["trace"]["window_s"]
@@ -216,11 +298,13 @@ def test_for_run_takes_the_newest_trace(chip, monkeypatch, tmp_path):
 
 def test_trace_of_a_program_without_spans_reads_nothing(monkeypatch):
     """The older chip trace (``batch_gen.xplane.pb.gz``) holds the
-    harness's spans only, as a program without spans records."""
+    harness's spans only, as a program without spans records: the span
+    readers read nothing, and no decode pairs with a dispatch span."""
     old = os.path.join(os.path.dirname(DATA), "batch_gen.xplane.pb.gz")
     monkeypatch.setattr(spans, "newest", lambda: old)
     run = spans.load(old)
-    ctx = {"trace": trace.reduce(trace.load(old)),
-           "decode_log": {k: [1] for k in run["calls"]}}
-    assert run["spans"] == [] and run["calls"]
-    assert read_all(ctx) == [None, None, None]
+    red = trace.reduce(trace.load(old))
+    assert run["spans"] == []
+    assert read_all({"trace": red, "traced_decodes": 3}) == [None] * 3
+    with pytest.raises(RuntimeError, match="matched"):
+        trace.check_decode(red)
